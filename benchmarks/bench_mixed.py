@@ -31,7 +31,6 @@ import tempfile
 import time
 
 JSON_PATH = "BENCH_mixed.json"
-ENV_DEVICES = 8
 BUDGET = 48             # gateway tells for the optimization section
 PARITY_POINTS = 48      # gram sample size for the parity section
 BRANCH_OFFSET = {"a": -4.0, "b": 0.0, "c": -2.0}
@@ -228,11 +227,9 @@ def _cell_8dev() -> dict:
 
 
 def _run_8dev_subprocess() -> dict:
-    env = dict(os.environ)
-    kept = [f for f in env.get("XLA_FLAGS", "").split()
-            if not f.startswith("--xla_force_host_platform_device_count")]
-    env["XLA_FLAGS"] = " ".join(
-        [f"--xla_force_host_platform_device_count={ENV_DEVICES}"] + kept)
+    """The 8-virtual-device cell in a CPU-only child process."""
+    from benchmarks.bench_shard import virtual_cpu_env
+    env = virtual_cpu_env()
     code = ("import json, benchmarks.bench_mixed as b;"
             "print('CELL::' + json.dumps(b._cell_8dev()))")
     out = subprocess.run([sys.executable, "-c", code], env=env,

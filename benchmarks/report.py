@@ -105,9 +105,9 @@ def _shard_table(d: dict) -> list[str]:
             f"| {r['suggestions_per_sec']:.0f} |")
     rows += ["",
              f"Headline: **{d['speedup_8v1_S64']:.2f}x** suggest-round "
-             "throughput at 8 devices vs 1 for S = 64 (2-core host; on a "
-             "real 8-accelerator mesh the per-device programs are identical "
-             "and the expected ratio approaches the device count)."]
+             "throughput at 8 devices vs 1 for S = 64 (2-core host, "
+             "virtual CPU devices; the ratio on accelerator chips is not "
+             "measured)."]
     return rows
 
 
@@ -202,8 +202,8 @@ def _serve_table(d: dict) -> list[str]:
         rows += ["",
                  "The RPC hop — micro-batched frames, base64 unit "
                  "buffers — amortizes against per-round device work: "
-                 "parity on a single-core host, and the per-shard rounds "
-                 "overlap physically once each worker has its own core."]
+                 "parity on a single-core host; whether per-shard rounds "
+                 "overlap with a core or chip per worker is not measured."]
     return rows
 
 

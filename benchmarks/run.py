@@ -41,6 +41,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_cholesky, bench_lag, bench_levy,
                             bench_mixed, bench_nn_hpo, bench_parallel,
                             bench_pool, bench_serve, bench_shard,

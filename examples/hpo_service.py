@@ -23,14 +23,17 @@ float tenants' ResNet space) through the very same batched rounds —
 heterogeneous type layouts share one stacked program (DESIGN.md §10).
 """
 import argparse
+import pathlib
 import sys
 import time
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 
 import numpy as np
 
-sys.path.insert(0, "src")
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
+from repro import compile_cache  # noqa: E402
 from repro.hpo.pool import SchedulerConfig, StudyPool  # noqa: E402
 from repro.hpo.space import (Categorical, RESNET_SPACE,  # noqa: E402
                              SearchSpace)
@@ -60,6 +63,7 @@ def make_objective(sid: int, latency: float, space=None):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--studies", type=int, default=8)
     ap.add_argument("--budget", type=int, default=12,

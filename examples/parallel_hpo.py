@@ -9,20 +9,24 @@ demonstrate the retry + penalized-region path, and the GP checkpoint in
 --ckpt-dir lets a second invocation resume the exact posterior.
 """
 import argparse
+import pathlib
 import sys
 import threading
 
 import numpy as np
 
-sys.path.insert(0, "src")
-sys.path.insert(0, ".")
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
+sys.path.insert(0, str(ROOT))
 
 from benchmarks.bench_nn_hpo import make_objective  # noqa: E402
+from repro import compile_cache  # noqa: E402
 from repro.hpo.scheduler import SchedulerConfig, TrialScheduler  # noqa: E402
 from repro.hpo.space import RESNET_SPACE  # noqa: E402
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--budget", type=int, default=16)
     ap.add_argument("--parallel", type=int, default=4)

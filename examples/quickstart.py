@@ -9,18 +9,22 @@ iteration; `--mode naive` refits the kernel and refactorizes fully (O(n^3))
 every iteration, which is the baseline the paper beats.
 """
 import argparse
+import pathlib
 import sys
 
 import numpy as np
 
-sys.path.insert(0, "src")
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
 import jax.numpy as jnp  # noqa: E402
 
+from repro import compile_cache  # noqa: E402
 from repro.core import levy_bounds, neg_levy, run_bo  # noqa: E402
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--iterations", type=int, default=120)
     ap.add_argument("--mode", default="lazy", choices=["lazy", "naive"])

@@ -24,14 +24,17 @@ shape where each tenant drives several training jobs at once.
 """
 import argparse
 import asyncio
+import pathlib
 import sys
 import tempfile
 import time
 
 import numpy as np
 
-sys.path.insert(0, "src")
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
+from repro import compile_cache  # noqa: E402
 from repro.core import GPCapacityError  # noqa: E402
 from repro.core.acquisition import AcqConfig  # noqa: E402
 from repro.hpo.gateway import GatewayConfig, StudyGateway  # noqa: E402
@@ -135,6 +138,7 @@ async def serve(args, ckpt_dir: str) -> None:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--studies", type=int, default=12,
                     help="concurrent logical studies (clients)")

@@ -24,14 +24,17 @@ from its own store) and each tenant resumes exactly where it stopped.
 """
 import argparse
 import asyncio
+import pathlib
 import sys
 import tempfile
 import time
 
 import numpy as np
 
-sys.path.insert(0, "src")
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
+from repro import compile_cache  # noqa: E402
 from repro.core.acquisition import AcqConfig  # noqa: E402
 from repro.hpo.federation import FederationConfig  # noqa: E402
 from repro.hpo.gateway import GatewayConfig  # noqa: E402
@@ -137,6 +140,7 @@ async def serve(args, root: str) -> None:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--studies", type=int, default=8,
                     help="concurrent logical studies (clients)")

@@ -11,10 +11,13 @@ committed step with an identical data stream.
 """
 import argparse
 import dataclasses
+import pathlib
 import sys
 
-sys.path.insert(0, "src")
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
+from repro import compile_cache  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.launch import train as train_mod  # noqa: E402
 
@@ -27,6 +30,7 @@ PRESETS = {
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="15m", choices=list(PRESETS))
     ap.add_argument("--arch", default=None,

@@ -178,7 +178,7 @@ def _make_eval_batch(state: gp_mod.LazyGPState, kernel: KernelFn,
     """
     if fused:
         amask = (jnp.arange(state.n_max) < state.n).astype(state.x_buf.dtype)
-        a_buf = state.li_buf.T @ state.li_buf
+        a_buf = ops.state_matmul(state.li_buf.T, state.li_buf)
         shift = ymean - f_best - cfg.xi
         cont_mask = getattr(kernel, "cont_mask", None)
         cat_mask = getattr(kernel, "cat_mask", None)

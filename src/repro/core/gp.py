@@ -487,7 +487,7 @@ def posterior(state: LazyGPState, kernel: KernelFn, x_star: Array,
     # v = L^{-1} k_* as a matmul against the maintained inverse (exact on
     # the padded buffers: k_* is zero beyond n).  Matmul-only keeps the EI
     # ascent batchable over the study axis (DESIGN.md §7).
-    v = state.li_buf @ k_star                                 # (n_max, m)
+    v = ops.state_matmul(state.li_buf, k_star)                # (n_max, m)
     k_ss = kernel(x_star, x_star, state.params)
     var = jnp.maximum(jnp.diag(k_ss) - jnp.sum(v * v, axis=0), 1e-12)
     return mean, var

@@ -273,24 +273,38 @@ class StudyEngine:
                     static_argnames=("top_t",), donate_argnums=(0,))
         # Routed single-study paths: plain jit; with a mesh active the
         # sharded state flows through GSPMD's auto-partitioner (these are
-        # the rare paths — lag events and per-study routing).  The mixed
-        # variants index the stacked descriptor at the same traced index.
+        # the rare paths — lag events and per-study routing), and the
+        # indexed study's compute runs replicated on every device (a
+        # Pallas kernel cannot be auto-partitioned).  The mixed variants
+        # index the stacked descriptor at the same traced index.
+        def routed(fn):
+            return fn if hpo_mesh is None else hpo_mesh.replicated(fn)
+
+        suggest_one_r = lambda top_t: routed(
+            lambda st, dsc, key: suggest_one(st, dsc, key, top_t, False))
+        append_one_r = routed(append_one)
+        fantasize_one_r = routed(fantasize_one)
+        refit_one_r = routed(refit_one)
+        reanchor_one_r = routed(reanchor_one)
+
         def ask_q_route(state, dsc, i, key, q):
             # q-suggestion fast path, routed to one slot: extract, run the
             # scan-of-(suggest + fantasize) program, scatter the fantasized
             # state back.  q is static — one compilation per distinct q.
-            xs, vals, sub = ask_q_one(_index_state(state, i), dsc, key, q)
+            xs, vals, sub = routed(
+                lambda st, d_, k: ask_q_one(st, d_, k, q))(
+                _index_state(state, i), dsc, key)
             return xs, vals, _write_state(state, i, sub)
 
         if mixed:
             self._suggest_at = jax.jit(
-                lambda state, dsc, i, key, *, top_t: suggest_one(
+                lambda state, dsc, i, key, *, top_t: suggest_one_r(top_t)(
                     _index_state(state, i),
-                    desc_mod.index_descriptor(dsc, i), key, top_t, False),
+                    desc_mod.index_descriptor(dsc, i), key),
                 static_argnames=("top_t",))
             self._append_at = jax.jit(
                 lambda state, dsc, i, x, y: _write_state(
-                    state, i, append_one(
+                    state, i, append_one_r(
                         _index_state(state, i),
                         desc_mod.index_descriptor(dsc, i), x, y)))
             self._ask_q_at = jax.jit(
@@ -299,42 +313,43 @@ class StudyEngine:
                 static_argnames=("q",))
             self._refantasize_at = jax.jit(
                 lambda state, dsc, i, xs: _write_state(
-                    state, i, fantasize_one(
+                    state, i, fantasize_one_r(
                         _index_state(state, i),
                         desc_mod.index_descriptor(dsc, i), xs)))
             self._refit_at = jax.jit(
                 lambda state, dsc, i: _write_state(
-                    state, i, refit_one(
+                    state, i, refit_one_r(
                         _index_state(state, i),
                         desc_mod.index_descriptor(dsc, i))))
             self._reanchor_at = jax.jit(
                 lambda state, dsc, i: _write_state(
-                    state, i, reanchor_one(
+                    state, i, reanchor_one_r(
                         _index_state(state, i),
                         desc_mod.index_descriptor(dsc, i))))
         else:
             self._suggest_at = jax.jit(
-                lambda state, i, key, *, top_t: suggest_one(
-                    _index_state(state, i), None, key, top_t, False),
+                lambda state, i, key, *, top_t: suggest_one_r(top_t)(
+                    _index_state(state, i), None, key),
                 static_argnames=("top_t",))
             self._append_at = jax.jit(
                 lambda state, i, x, y: _write_state(
-                    state, i, append_one(_index_state(state, i), None,
-                                         x, y)))
+                    state, i, append_one_r(_index_state(state, i), None,
+                                           x, y)))
             self._ask_q_at = jax.jit(
                 lambda state, i, key, *, q: ask_q_route(
                     state, None, i, key, q),
                 static_argnames=("q",))
             self._refantasize_at = jax.jit(
                 lambda state, i, xs: _write_state(
-                    state, i, fantasize_one(_index_state(state, i), None,
-                                            xs)))
+                    state, i, fantasize_one_r(_index_state(state, i), None,
+                                              xs)))
             self._refit_at = jax.jit(
                 lambda state, i: _write_state(
-                    state, i, refit_one(_index_state(state, i), None)))
+                    state, i, refit_one_r(_index_state(state, i), None)))
             self._reanchor_at = jax.jit(
                 lambda state, i: _write_state(
-                    state, i, reanchor_one(_index_state(state, i), None)))
+                    state, i, reanchor_one_r(_index_state(state, i),
+                                             None)))
         # Fantasy rollback: re-pad every row >= n_real of one slot (kernel-
         # free, descriptor-free — identical trace in mixed mode).
         self._truncate_at = jax.jit(

@@ -40,7 +40,6 @@ import dataclasses
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 STUDY_AXIS = "study"
@@ -87,11 +86,21 @@ class HPOMesh:
         arity); each shard sees the local `(S/study_shards, ...)` slice.
         Outputs must be replicated across the restart axis (each restart
         shard computes them identically after its `all_gather`), which
-        `check_rep=False` asserts by fiat rather than proof.
+        `check_vma=False` asserts by fiat rather than proof.
         """
-        return shard_map(body, self.mesh,
-                         in_specs=(P(STUDY_AXIS),) * n_in,
-                         out_specs=P(STUDY_AXIS), check_rep=False)
+        return jax.shard_map(body, mesh=self.mesh,
+                             in_specs=(P(STUDY_AXIS),) * n_in,
+                             out_specs=P(STUDY_AXIS), check_vma=False)
+
+    def replicated(self, body):
+        """Run a single-study computation replicated on every device.
+
+        The routed paths index one study out of the sharded stack; a Pallas
+        kernel on that sub-state cannot be partitioned by GSPMD, so the
+        sub-state is replicated and each device computes it under
+        `shard_map` (identical results, written back by the caller)."""
+        return jax.shard_map(body, mesh=self.mesh, in_specs=P(),
+                             out_specs=P(), check_vma=False)
 
 
 def parse_spec(spec: str) -> tuple[int, int] | str | None:

@@ -72,6 +72,7 @@ import subprocess
 import sys
 import time
 
+import jax
 import numpy as np
 
 from repro import checkpoint as ckpt_mod
@@ -162,13 +163,53 @@ def _decode_error(msg: dict) -> Exception:
     return TransportError(f"shard worker raised {etype}: {text}")
 
 
+def tpu_chips_on_host() -> int:
+    """TPU chips attached to this host, counted from PCI ids the way JAX's
+    own start-up counts them (no backend is initialized)."""
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def refuse_chip_contention(env: dict, n_spawned: int) -> None:
+    """Fail fast before spawning shard workers that would fight over a chip.
+
+    A chip belongs to one process, and libtpu admits one process per host.
+    The front end initializes no JAX backend of its own before it spawns,
+    but every spawned worker whose `JAX_PLATFORMS` admits the TPU loads
+    libtpu and claims every chip: with two such workers, or one beside a
+    front end that already holds the chip, all but one fail or hang.  So on
+    a TPU host at most one spawned worker may reach for the chips; the rest
+    run with `JAX_PLATFORMS=cpu`, or are started one per host and adopted
+    with `TransportConfig(connect=...)`, or the chips are served from one
+    process over the study-axis mesh (`SchedulerConfig(mesh=...)`).
+    """
+    platforms = env.get("JAX_PLATFORMS", "").strip().lower()
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    chips = tpu_chips_on_host()
+    if not chips:
+        return
+    from jax._src import xla_bridge
+    front_holds = (xla_bridge.backends_are_initialized()
+                   and jax.default_backend() == "tpu")
+    if n_spawned > 1 or front_holds:
+        holder = " beside a front end that holds the chips" \
+            if front_holds else ""
+        raise TransportError(
+            f"refusing to spawn {n_spawned} shard worker(s){holder} on a "
+            f"host with {chips} TPU chip(s): each would load libtpu and "
+            "contend for the chips (a chip belongs to one process).  "
+            "Spawn CPU workers with JAX_PLATFORMS=cpu, start one worker per "
+            "host and pass TransportConfig(connect=...), or serve the chips "
+            "from one process with SchedulerConfig(mesh=...)")
+
+
 def study_state_digest(pool, slot: int) -> str:
     """sha256 over every leaf of one slot's GP state (leaf-path sorted).
     The wire-safe BITWISE comparison surface: two gateways serving the
     same study identically must agree on this digest exactly — the
     equivalence suites compare it across process boundaries where the
     raw buffers can't travel."""
-    import jax
     st = pool.engine.study_state(slot)
     leaves = {jax.tree_util.keystr(path): np.asarray(leaf).tobytes()
               for path, leaf in
@@ -715,6 +756,8 @@ class TransportFederation(FederationBase):
         pkg_root = os.path.dirname(os.path.dirname(repro.__file__))
         env = dict(os.environ)
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+        endpoints = self.transport.connect or ("",) * self.fed.n_shards
+        refuse_chip_contention(env, sum(1 for ep in endpoints if not ep))
         proc = subprocess.Popen(
             [self.transport.python, "-m", "repro.hpo.shard_worker",
              "--ckpt-dir", d], env=env)

@@ -38,7 +38,9 @@ flash-attention-style, and never round-trip through HBM.  `ops.py` owns
 padding, the block-size autotuner, and the mask split; beyond its VMEM
 residency bound it falls back to `ei_grad_jnp` (the same math as one fused
 XLA program — this is also the "xla"/"ref" oracle the parity suite pins
-the kernel against).
+the kernel against), with a `KernelFallbackWarning`.  The kernel body
+uses only what the TPU compiler lowers: `erf` is built from `exp`
+(`erfc` below), and every row reduction keeps its 2-D layout.
 """
 from __future__ import annotations
 
@@ -47,23 +49,57 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.chol import vmem_limit
 
 Array = jax.Array
 
 # Variance clamp shared with `gp.posterior` — the fused gradient mirrors
 # autodiff of this exact floor.
 VAR_FLOOR = 1e-12
+# The kernel's matmuls run at full f32: at TPU's default (one bf16 pass)
+# the posterior variance behind EI errs by a sizeable share of sigma2, and
+# the ascent then climbs precision noise.
+_HI = jax.lax.Precision.HIGHEST
 _SQRT5 = 2.23606797749979
 _SQRT2 = 1.4142135623730951
 _INV_SQRT_2PI = 0.3989422804014327
+# Chebyshev coefficients of `erfc` (Numerical Recipes, "erfcc").
+_ERFC_COEFFS = (-1.26551223, 1.00002368, 0.37409196, 0.09678418,
+                -0.18628806, 0.27886807, -1.13520398, 1.48851587,
+                -0.82215223, 0.17087277)
+
+
+def erfc(x: Array) -> Array:
+    """Complementary error function from one `exp` and one division.
+
+    The TPU kernel compiler has no `erf`, so the EI's normal CDF is built
+    from this Chebyshev fit (Numerical Recipes' `erfcc`): its fractional
+    error is below 1.2e-7 for every x in exact arithmetic, i.e. at most
+    2.4e-7 absolute against `erf` (erfc <= 2) once used as
+    `erf(x) = 1 - erfc(x)`.  In float32 the rounding of `exp`'s argument
+    (a few ulps of x^2) adds relative error in the tails;
+    `tests/test_fused_acq.py` pins both.  Unlike `0.5 (1 + erf(z))`,
+    `0.5 erfc(-z / sqrt2)` keeps its relative accuracy deep in the lower
+    tail, where EI lives far from the incumbent.
+    """
+    z = jnp.abs(x)
+    t = 1.0 / (1.0 + 0.5 * z)
+    poly = _ERFC_COEFFS[-1]
+    for c in _ERFC_COEFFS[-2::-1]:
+        poly = c + t * poly
+    ans = t * jnp.exp(-z * z + poly)
+    return jnp.where(x >= 0.0, ans, 2.0 - ans)
 
 
 def _sqdist(a: Array, b: Array) -> Array:
     """|a - b|^2 via the MXU-friendly expansion (same tiling as matern.py)."""
-    aa = jnp.sum(a * a, axis=-1)[:, None]
+    aa = jnp.sum(a * a, axis=-1, keepdims=True)
     bb = jnp.sum(b * b, axis=-1)[None, :]
     cross = jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        a, b, (((1,), (1,)), ((), ())), precision=_HI,
+        preferred_element_type=jnp.float32)
     return jnp.maximum(aa + bb - 2.0 * cross, 0.0)
 
 
@@ -94,17 +130,18 @@ def _fused_ei_grad_math(xc, xbc, amask, alpha, a_buf, sigma2, rho, shift,
     else:
         cat = 1.0
     km = k * amask                                           # (r, n)
-    gam = jax.lax.dot_general(                               # (r, 1)
-        km, alpha, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) + shift
+    # A lane reduction, not a matvec: the TPU compiler folds a scalar added
+    # to a matmul result into its accumulator, which it refuses when the
+    # scalar is read from a ref.
+    gam = jnp.sum(km * alpha, axis=-1, keepdims=True) + shift   # (r, 1)
     u = jax.lax.dot_general(                                 # (r, n)
-        km, a_buf, (((1,), (0,)), ((), ())),
+        km, a_buf, (((1,), (0,)), ((), ())), precision=_HI,
         preferred_element_type=jnp.float32)
-    raw_var = sigma2 - jnp.sum(u * km, axis=-1)[:, None]     # (r, 1)
+    raw_var = sigma2 - jnp.sum(u * km, axis=-1, keepdims=True)  # (r, 1)
     var = jnp.maximum(raw_var, VAR_FLOOR)
     sig = jnp.sqrt(var)
     zs = gam / jnp.maximum(sig, 1e-12)
-    cdf = 0.5 * (1.0 + jax.lax.erf(zs / _SQRT2))
+    cdf = 0.5 * erfc(-zs / _SQRT2)
     pdf = jnp.exp(-0.5 * zs * zs) * _INV_SQRT_2PI
     ei = jnp.maximum(gam * cdf + sig * pdf, 0.0)             # (r, 1)
     # dEI/dvar = phi(Z) / 2 sigma, dead where the raw variance hit the
@@ -113,8 +150,8 @@ def _fused_ei_grad_math(xc, xbc, amask, alpha, a_buf, sigma2, rho, shift,
     c = cdf * (alpha * amask) - 2.0 * dvar * u               # dEI/dK (r, n)
     s = (-sigma2 * (5.0 / (3.0 * rho * rho))) * (1.0 + z) * ez * cat
     w = c * s * amask                                        # (r, n)
-    grad = jnp.sum(w, axis=-1)[:, None] * xc - jax.lax.dot_general(
-        w, xbc, (((1,), (0,)), ((), ())),
+    grad = jnp.sum(w, axis=-1, keepdims=True) * xc - jax.lax.dot_general(
+        w, xbc, (((1,), (0,)), ((), ())), precision=_HI,
         preferred_element_type=jnp.float32)
     return ei, grad
 
@@ -208,6 +245,11 @@ def fused_ei_grad_pallas(xc: Array, xbc: Array, amask: Array, alpha: Array,
                    pl.BlockSpec((block_r, d), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((r, 128), xc.dtype),
                    jax.ShapeDtypeStruct((r, d), xc.dtype)],
+        # Inside a whole program XLA may place the batched operands and
+        # outputs in VMEM too, on top of the blocks; the 16 MiB default
+        # overflowed at n = 1024 on a v5e.  Twice the blocks' bytes again.
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            2 * 4 * (n * n + 2 * n * d + 2 * n + 4 * block_r * (d + 128)))),
         interpret=interpret,
     )(*operands)
     return ei[:, 0], g

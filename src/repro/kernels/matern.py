@@ -34,7 +34,8 @@ def _matern_tile_kernel(x_ref, y_ref, sig_ref, out_ref):
     xx = jnp.sum(x * x, axis=-1)[:, None]       # (bn, 1)
     yy = jnp.sum(y * y, axis=-1)[None, :]       # (1, bm)
     cross = jax.lax.dot_general(                # MXU: (bn, d) x (bm, d)^T
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, y, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)     # full f32: the sum cancels
     sq = jnp.maximum(xx + yy - 2.0 * cross, 0.0)
     dist = jnp.sqrt(sq + 1e-36)
     z = jnp.sqrt(5.0) * dist / rho

@@ -43,6 +43,7 @@ def _mixed_tile_kernel(xc_ref, yc_ref, xk_ref, yk_ref, par_ref, out_ref):
         bb = jnp.sum(b * b, axis=-1)[None, :]
         cross = jax.lax.dot_general(            # MXU: (bn, d) x (bm, d)^T
             a, b, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # full f32: the sum cancels
             preferred_element_type=jnp.float32)
         return jnp.maximum(aa + bb - 2.0 * cross, 0.0)
 

@@ -83,9 +83,11 @@ alpha refresh in four matvec passes over one factor residency.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import os
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +102,10 @@ from repro.kernels.trsv import trsv_pallas
 Array = jax.Array
 
 ALIGN = 128
-# Whole-factor VMEM residency bound (f32): 1024^2 * 4 B * (in + out) = 8 MB.
+# Whole-factor VMEM residency bound of the Cholesky and trsv kernels (f32).
+# At 2048 the Cholesky's input and output blocks take 32 MiB; the kernels
+# request that scoped VMEM explicitly (`chol.vmem_limit`), and
+# tests/test_tpu_compile.py compiles both at this bound for a v5e.
 MAX_PALLAS_N = 2048
 # Floor for the squared new-diagonal d^2 = c - q.q in the incremental append.
 # The paper's lemma guarantees d^2 > 0 in exact arithmetic; hitting this floor
@@ -108,6 +113,29 @@ MAX_PALLAS_N = 2048
 CLAMP_EPS = 1e-10
 
 IMPLEMENTATIONS = ("auto", "pallas", "xla", "ref")
+
+
+class KernelFallbackWarning(UserWarning):
+    """A call that would run a Pallas kernel exceeds the kernel's size bound
+    and is served on the XLA path instead (raised once per call site and
+    size, at trace time)."""
+
+
+def _warn_fallback(op: str, n: int, bound: int) -> None:
+    warnings.warn(
+        f"{op}: n={n} exceeds the Pallas kernel's bound {bound}; this call "
+        "runs on the XLA path", KernelFallbackWarning, stacklevel=3)
+
+
+def state_matmul(a: Array, b: Array) -> Array:
+    """Matmul against the maintained inverse `li_buf` at full f32.
+
+    TPU runs a default-precision f32 matmul as one bf16 pass.  The posterior
+    variance `k** - |li_buf k*|^2` then erred by up to 0.3 sigma2 at n = 259
+    on a v5e, and the EI variance `k^T A k` with `A = li_buf^T li_buf` is
+    the same sum.  (The appends' matvecs stayed exact at either precision
+    there, so they keep the default.)"""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def _on_tpu() -> bool:
@@ -182,6 +210,8 @@ def trsv(l: Array, b: Array, *, trans: bool = False,
          implementation: str = "auto") -> Array:
     """Triangular solve L q = b / L^T q = b; b (n,) or (n, r)."""
     use, interp = _use_pallas(implementation)
+    if use and l.shape[0] > MAX_PALLAS_N:
+        _warn_fallback("trsv", l.shape[0], MAX_PALLAS_N)
     if implementation == "ref" or not use or l.shape[0] > MAX_PALLAS_N:
         return ref.trsv_ref(l, b, trans=trans)
     n = l.shape[0]
@@ -203,6 +233,8 @@ def trsv(l: Array, b: Array, *, trans: bool = False,
 def cholesky(k: Array, implementation: str = "auto") -> Array:
     """Blocked Cholesky of an SPD matrix (lower factor)."""
     use, interp = _use_pallas(implementation)
+    if use and k.shape[0] > MAX_PALLAS_N:
+        _warn_fallback("cholesky", k.shape[0], MAX_PALLAS_N)
     if implementation == "ref" or not use or k.shape[0] > MAX_PALLAS_N:
         return ref.cholesky_ref(k)
     n = k.shape[0]
@@ -489,8 +521,9 @@ def lazy_append_rows(l_buf: Array, li_buf: Array, p_pads: Array, cs: Array,
 # Fused EI-ascent megakernel + block-size autotuner (DESIGN.md §11).
 # ---------------------------------------------------------------------------
 
-# Whole-A VMEM residency bound for the megakernel (f32): 1024^2 * 4 B = 4 MB
-# for A alone; beyond this the fused jnp formulation takes over.
+# Whole-A VMEM residency bound for the megakernel (f32): 1024^2 * 4 B = 4 MiB
+# for A alone, double-buffered under the study-axis grid; beyond this the
+# fused jnp formulation takes over (with a KernelFallbackWarning).
 MAX_ACQ_PALLAS_N = 1024
 # Candidate-tile row counts the autotuner races (all >= the f32 sublane
 # minimum of 8; the default restart count R = 64 pads to one or two tiles).
@@ -535,9 +568,12 @@ def _measure_acq_config(block_r: int, d_pad: int, n_pad: int, s: int) -> float:
     """Wall-time one tile config on dummy operands (best of 3, seconds).
 
     Only meaningful on a compiled backend; `acq_tile_config` never calls it
-    in interpret mode.  Measures the single-study call — the study axis
-    batches to an extra grid dimension, which scales every candidate
-    equally and preserves the ranking.
+    in interpret mode, and calls it from a thread of its own, so that even
+    inside a trace the operands are concrete arrays and the kernel really
+    runs on the device before `block_until_ready` returns.  Measures
+    the single-study call — the study axis batches to an extra grid
+    dimension, which scales every candidate equally and preserves the
+    ranking.
     """
     del s
     r = 2 * block_r
@@ -572,7 +608,9 @@ def acq_tile_config(n_pad: int, d: int, s: int, interpret: bool,
     timings reflect the emulator, not the hardware) so CPU-emulated runs
     stay deterministic.  `measure_fn(block_r, d_pad, n_pad, s) -> seconds`
     is injectable for tests.  Runs host-side at trace time — the choice is
-    baked into the jitted program.
+    baked into the jitted program.  The race runs in a worker thread: JAX's
+    trace state is per thread, so the race times the kernel on the device
+    and not the tracing of it.
     """
     d_pad = max(ALIGN, next_power_of_2(d))
     heuristic = AcqTileConfig(block_r=ACQ_DEFAULT_BLOCK_R, d_pad=d_pad,
@@ -587,14 +625,19 @@ def acq_tile_config(n_pad: int, d: int, s: int, interpret: bool,
         cfg = heuristic
     else:
         fn = measure_fn or _measure_acq_config
-        best, best_t = ACQ_DEFAULT_BLOCK_R, float("inf")
-        for block_r in ACQ_BLOCK_R_CANDIDATES:
-            t = fn(block_r, d_pad, n_pad, s)
-            if t < best_t:
-                best, best_t = block_r, t
+        with concurrent.futures.ThreadPoolExecutor(1) as worker:
+            times = worker.submit(lambda: [
+                float(fn(block_r, d_pad, n_pad, s))
+                for block_r in ACQ_BLOCK_R_CANDIDATES]).result()
+        best = ACQ_BLOCK_R_CANDIDATES[times.index(min(times))]
         cfg = AcqTileConfig(block_r=best, d_pad=d_pad, measured=True)
     _ACQ_TUNE_CACHE[key] = cfg
     return cfg
+
+
+def tuned_acq_tiles() -> dict[tuple, AcqTileConfig]:
+    """The tile configs picked so far, keyed `(n_pad, d, S, substrate)`."""
+    return dict(_ACQ_TUNE_CACHE)
 
 
 def fused_supported(kernel_fn, acq_name: str) -> bool:
@@ -639,6 +682,8 @@ def fused_ei_grad(x: Array, x_buf: Array, amask: Array, alpha: Array,
     """
     use, interp = _use_pallas(implementation)
     n_max = x_buf.shape[0]
+    if use and n_max > MAX_ACQ_PALLAS_N:
+        _warn_fallback("fused_ei_grad", n_max, MAX_ACQ_PALLAS_N)
     if not use or n_max > MAX_ACQ_PALLAS_N:
         return acq_kernels.ei_grad_jnp(
             x, x_buf, amask.astype(x.dtype), alpha, a_buf, sigma2, rho,

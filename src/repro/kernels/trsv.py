@@ -12,8 +12,12 @@ Supports matrix right-hand sides (n, r) so the GP posterior's ``L^{-1} K_*``
 solve reuses the same kernel, and a ``trans`` variant (backward substitution
 on L^T) for the alpha refresh.
 
-The whole factor stays VMEM-resident: n <= 1024 keeps L at 4 MB (f32), within
-every TPU generation's VMEM.  `ops.py` falls back to XLA beyond the envelope.
+The whole factor stays VMEM-resident (16 MiB at `ops.MAX_PALLAS_N` = 2048,
+f32) while the grid walks the right-hand side in 128-column slabs.  Every
+slice is a static ref slice, and the 128x128 diagonal solves pick rows and
+columns out with masked reductions: the TPU compiler lowers no dynamic
+slice of an in-register value.  `ops.py` hands larger n to XLA, with a
+`KernelFallbackWarning`.
 """
 from __future__ import annotations
 
@@ -22,92 +26,70 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.chol import (solve_lower_block, solve_upper_block,
+                                vmem_limit)
 
 Array = jax.Array
 
 BLOCK = 128
-
-
-def _solve_diag_lower(ldiag: Array, rhs: Array) -> Array:
-    """Unblocked forward substitution on a (B, B) lower block, rhs (B, r)."""
-    b = ldiag.shape[0]
-    idx = jnp.arange(b)
-
-    def row(i, q):
-        mask = (idx < i).astype(ldiag.dtype)            # strictly-lower row i
-        li = ldiag[i, :] * mask                          # (B,)
-        r = (rhs[i, :] - li @ q) / ldiag[i, i]           # (r,)
-        return jnp.where((idx == i)[:, None], r[None, :], q)
-
-    return jax.lax.fori_loop(0, b, row, jnp.zeros_like(rhs))
-
-
-def _solve_diag_upper(udiag: Array, rhs: Array) -> Array:
-    """Unblocked backward substitution on a (B, B) upper block, rhs (B, r)."""
-    b = udiag.shape[0]
-    idx = jnp.arange(b)
-
-    def row(step, q):
-        i = b - 1 - step
-        mask = (idx > i).astype(udiag.dtype)
-        ui = udiag[i, :] * mask
-        r = (rhs[i, :] - ui @ q) / udiag[i, i]
-        return jnp.where((idx == i)[:, None], r[None, :], q)
-
-    return jax.lax.fori_loop(0, b, row, jnp.zeros_like(rhs))
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _trsv_kernel(l_ref, b_ref, out_ref, *, trans: bool, n_blocks: int):
-    l = l_ref[...].astype(jnp.float32)      # (n, n) lower-triangular factor
-    rhs = b_ref[...].astype(jnp.float32)    # (n, r)
-    n = l.shape[0]
-
-    def fwd_step(kb, q):
-        s = kb * BLOCK
-        lrow = jax.lax.dynamic_slice(l, (s, 0), (BLOCK, n))       # (B, n)
-        # q is zero at rows >= s, so lrow @ q == L[s:s+B, :s] @ q[:s].
-        part = jax.lax.dot_general(lrow, q, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        blk_rhs = jax.lax.dynamic_slice(rhs, (s, 0), (BLOCK, rhs.shape[1]))
-        ldiag = jax.lax.dynamic_slice(l, (s, s), (BLOCK, BLOCK))
-        qblk = _solve_diag_lower(ldiag, blk_rhs - part)
-        return jax.lax.dynamic_update_slice(q, qblk, (s, 0))
-
-    def bwd_step(step, q):
-        kb = n_blocks - 1 - step
-        s = kb * BLOCK
-        lcol = jax.lax.dynamic_slice(l, (0, s), (n, BLOCK))       # (n, B)
-        # Row block of L^T = lcol^T; q zero at rows < s + B not yet solved.
-        part = jax.lax.dot_general(lcol, q, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        blk_rhs = jax.lax.dynamic_slice(rhs, (s, 0), (BLOCK, rhs.shape[1]))
-        udiag = jax.lax.dynamic_slice(l, (s, s), (BLOCK, BLOCK)).T
-        qblk = _solve_diag_upper(udiag, blk_rhs - part)
-        return jax.lax.dynamic_update_slice(q, qblk, (s, 0))
-
-    q0 = jnp.zeros_like(rhs)
-    step = bwd_step if trans else fwd_step
-    out_ref[...] = jax.lax.fori_loop(0, n_blocks, step, q0).astype(out_ref.dtype)
+    """q = L^{-1} b (or L^{-T} b) for one 128-column slab of the right-hand
+    side, into `out_ref`, one 128-row block at a time; the solved blocks in
+    `out_ref` feed the later blocks' updates."""
+    n = n_blocks * BLOCK
+    order = range(n_blocks - 1, -1, -1) if trans else range(n_blocks)
+    for kb in order:                              # static block offsets
+        s, e = kb * BLOCK, (kb + 1) * BLOCK
+        rhs = b_ref[s:e, :].astype(jnp.float32)
+        ldiag = l_ref[s:e, s:e].astype(jnp.float32)
+        if trans:
+            if e < n:
+                # Row block of L^T = (L[e:, s:e])^T against the solved rows.
+                rhs = rhs - jax.lax.dot_general(
+                    l_ref[e:, s:e].astype(jnp.float32), out_ref[e:, :],
+                    (((0,), (0,)), ((), ())), precision=_HI,
+                    preferred_element_type=jnp.float32)
+            qblk = solve_upper_block(ldiag.T, rhs)
+        else:
+            if s > 0:
+                rhs = rhs - jax.lax.dot_general(
+                    l_ref[s:e, :s].astype(jnp.float32), out_ref[:s, :],
+                    (((1,), (0,)), ((), ())), precision=_HI,
+                    preferred_element_type=jnp.float32)
+            qblk = solve_lower_block(ldiag, rhs)
+        out_ref[s:e, :] = qblk
 
 
 @functools.partial(jax.jit, static_argnames=("trans", "interpret"))
 def _trsv_pallas_raw(l: Array, b: Array, *, trans: bool = False,
                      interpret: bool = False) -> Array:
-    """The raw pallas_call (no AD rule — wrapped by the custom VJP below)."""
-    n = l.shape[0]
-    assert n % BLOCK == 0, n
-    assert b.ndim == 2 and b.shape[0] == n, b.shape
+    """The raw pallas_call (no AD rule — wrapped by the custom VJP below).
+
+    The grid walks the right-hand side in 128-column slabs (the columns are
+    independent solves); the factor is one single-buffered block that stays
+    resident across the whole grid."""
+    n, r = b.shape
+    assert n % BLOCK == 0 and r % BLOCK == 0 and l.shape == (n, n), \
+        (l.shape, b.shape)
     kernel = functools.partial(_trsv_kernel, trans=trans, n_blocks=n // BLOCK)
+    slab = pl.BlockSpec((n, BLOCK), lambda j: (0, j))
     return pl.pallas_call(
         kernel,
-        in_specs=[
-            pl.BlockSpec((n, n), lambda: (0, 0)),
-            pl.BlockSpec((n, b.shape[1]), lambda: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, b.shape[1]), lambda: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
+        grid=(r // BLOCK,),
+        in_specs=[pl.BlockSpec((n, n), lambda j: (0, 0),
+                               pipeline_mode=pl.Buffered(1)),
+                  slab],
+        out_specs=slab,
+        out_shape=jax.ShapeDtypeStruct(b.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(4 * n * n + 2 * 2 * 4 * n * BLOCK)),
         interpret=interpret,
-    )(l, b)
+    )(l, b).astype(b.dtype)
 
 
 # `pallas_call` has no linearization rule, but the acquisition optimizer

@@ -222,9 +222,6 @@ def _compile_once(arch, shape_name, mesh, *, seq_parallel, opt_overrides,
         compiled = lowered.compile()
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # Older jax returns a one-element list of per-module dicts.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     census = collective_census(hlo, n_devices=mesh.size)
     if top_colls:

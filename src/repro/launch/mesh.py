@@ -13,12 +13,8 @@ SINGLE_POD = (16, 16)              # 256 chips (v5e pod)
 MULTI_POD = (2, 16, 16)            # 2 pods = 512 chips
 
 
-def _axis_type_kwargs(n):
-    """`axis_types` only exists on newer jax; older versions default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def _auto_axes(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -35,7 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (see launch/dryrun.py)")
     return jax.make_mesh(shape, axes, devices=devices[:need],
-                         **_axis_type_kwargs(len(shape)))
+                         axis_types=_auto_axes(len(shape)))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
@@ -44,9 +40,9 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
     for s in shape:
         need *= s
     return jax.make_mesh(shape, axes, devices=jax.devices()[:need],
-                         **_axis_type_kwargs(len(shape)))
+                         axis_types=_auto_axes(len(shape)))
 
 
 def single_device_mesh() -> Mesh:
     return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1], **_axis_type_kwargs(2))
+                         devices=jax.devices()[:1], axis_types=_auto_axes(2))

@@ -9,6 +9,8 @@ and the loop-invariant hoists (`_f_best`, `_ymean` once per suggest call)
 are pinned by a trace-count test so a refactor can't silently re-inline
 them into the ascent loop.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -193,6 +195,60 @@ def test_autotuner_interpret_defaults_to_heuristic():
     assert not cfg.measured
     assert cfg.block_r == ops.ACQ_DEFAULT_BLOCK_R
     assert ops.acq_tile_config(256, 5, 1, True) == cfg
+
+
+def test_autotuner_times_concrete_arrays_inside_a_trace(monkeypatch):
+    """The tuner runs while the engine's closures are traced; its race must
+    still run the real kernel on concrete arrays (a tracer would time the
+    tracing, and the kernel must trace cleanly from there)."""
+    monkeypatch.setenv("REPRO_ACQ_AUTOTUNE", "on")
+    from repro.kernels.acq import fused_ei_grad_pallas
+    seen = []
+
+    def measure(block_r, d_pad, n_pad, s):
+        row = jnp.zeros((1, n_pad))
+        ei, g = fused_ei_grad_pallas(
+            jnp.ones((2 * block_r, d_pad)), jnp.zeros((n_pad, d_pad)), row,
+            row, jnp.zeros((n_pad, n_pad)), 1.0, 0.25, 0.0,
+            block_r=block_r, interpret=True)
+        jax.block_until_ready((ei, g))
+        seen.append(isinstance(g, jax.core.Tracer))
+        # float() of a tracer raises: the timing is a concrete number
+        return float(jnp.sum(ei)) * 0.0 + abs(block_r - 32)
+
+    @jax.jit
+    def traced(v):
+        cfg = ops.acq_tile_config(128, 5, 1, False, measure_fn=measure)
+        return v * cfg.block_r
+
+    assert float(traced(1.0)) == 32.0
+    assert len(seen) == len(ops.ACQ_BLOCK_R_CANDIDATES) and not any(seen)
+    assert ops._ACQ_TUNE_CACHE[(128, 5, 1, "compiled")].measured
+
+
+# The ascent's standardized improvement Z = gamma / sigma; the CDF takes
+# erfc(-Z / sqrt2).  |Z| > 8.5 puts Phi within 1e-17 of 0 or 1.
+ERF_RANGE = np.linspace(-6.0, 6.0, 4801)
+
+
+def test_erfc_replacement_error_against_erf():
+    from repro.kernels import acq
+    got = np.asarray(acq.erfc(jnp.asarray(ERF_RANGE, jnp.float32)),
+                     np.float64)
+    exact = np.array([math.erfc(v) for v in ERF_RANGE])
+    # As erf = 1 - erfc: the fit's fractional 1.2e-7 of erfc <= 2, plus
+    # half a float32 ulp at 2 — and, against jax.lax.erf, that function's
+    # own float32 error (about 2e-7 over this range).
+    erf_exact = np.array([math.erf(v) for v in ERF_RANGE])
+    assert np.abs((1.0 - got) - erf_exact).max() <= 3.6e-7
+    erf_lax = np.asarray(jax.lax.erf(jnp.asarray(ERF_RANGE, jnp.float32)),
+                         np.float64)
+    assert np.abs((1.0 - got) - erf_lax).max() <= 6e-7
+    # Relative error of erfc itself, including the lower tail of Phi where
+    # 0.5 * (1 + erf) would cancel: dominated by the float32 rounding of
+    # exp's argument -x^2 + poly (a few ulps of about x^2).
+    rel = np.abs(got - exact) / exact
+    assert np.all(rel <= 2.4e-7 * (ERF_RANGE ** 2 + 2.0)), rel.max()
 
 
 def test_next_power_of_2():

@@ -134,6 +134,8 @@ def test_gp_posterior_solve_matches_ref():
 def test_large_n_falls_back_to_xla():
     n = ops.MAX_PALLAS_N + 128
     k = jnp.eye(n) * 2.0
-    l = ops.cholesky(k, implementation="pallas")  # falls back, still correct
+    # falls back, says so, and is still correct
+    with pytest.warns(ops.KernelFallbackWarning, match="cholesky"):
+        l = ops.cholesky(k, implementation="pallas")
     np.testing.assert_allclose(np.asarray(jnp.diag(l)),
                                np.full(n, np.sqrt(2.0)), rtol=1e-6)

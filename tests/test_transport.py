@@ -104,6 +104,29 @@ def test_spec_roundtrip_rebuilds_the_same_gateway_shape(tmp_path):
 # ---------------------------------------------------------------------------
 # Cross-deployment equivalence: 2 worker processes == 1 in-process pool
 # ---------------------------------------------------------------------------
+def test_spawn_refuses_workers_that_would_share_a_chip(monkeypatch,
+                                                      tmp_path):
+    """A chip belongs to one process: on a TPU host, spawned workers that
+    would each load libtpu fail fast, before any process starts."""
+    monkeypatch.setattr(tx, "tpu_chips_on_host", lambda: 4)
+    tpu_env = {"JAX_PLATFORMS": ""}
+    with pytest.raises(TransportError, match="4 TPU chip"):
+        tx.refuse_chip_contention(tpu_env, 2)
+    tx.refuse_chip_contention(tpu_env, 1)       # one worker may hold them
+    tx.refuse_chip_contention({"JAX_PLATFORMS": "cpu"}, 2)  # CPU workers
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    tf = _mk_tf(str(tmp_path))
+
+    async def main():
+        with pytest.raises(TransportError, match="refusing to spawn 2"):
+            await tf.start()
+
+    asyncio.run(main())
+    assert tf.procs == [None, None]
+    monkeypatch.setattr(tx, "tpu_chips_on_host", lambda: 0)
+    tx.refuse_chip_contention(tpu_env, 2)       # no chips: nothing to share
+
+
 def test_two_process_federation_matches_single_pool_bitwise():
     """The acceptance bar of DESIGN.md §13 extended across process
     boundaries: WHERE a study is served (one pool, or 2 shard processes
