@@ -62,6 +62,7 @@ from repro.core import gp as gp_mod
 from repro.core import neural_basis as nb_mod
 from repro.core.kernels import KERNELS, make_mixed_kernel
 from repro.hpo import mesh as mesh_mod
+from repro.hpo.telemetry import span
 
 Array = jax.Array
 
@@ -537,7 +538,8 @@ class StudyEngine:
         the updated posteriors, returning `((S, top_t, d), (S, top_t))`.
         This is the serving-loop hot path: one program per round instead of
         an absorb dispatch + a suggest dispatch, with the stacked state
-        buffers donated (updated in place, not copied).
+        buffers donated (updated in place, not copied).  The dispatch is
+        the `engine.advance` span.
 
         The previous `self.state` is consumed by donation — callers must
         not hold references to its buffers across this call.  Pipelined
@@ -552,11 +554,12 @@ class StudyEngine:
         for s in flagged:
             gp_mod.ensure_capacity(self.n(s), self.cfg.n_max)
         self._record_costs(flagged, costs)
-        self._state, units, vals = self._advance_all(
-            self.state, *self._desc_args(),
-            jnp.asarray(xs, jnp.float32),
-            jnp.asarray(ys, jnp.float32),
-            jnp.asarray(flags), keys, top_t=top_t)
+        with span("engine.advance"):
+            self._state, units, vals = self._advance_all(
+                self.state, *self._desc_args(),
+                jnp.asarray(xs, jnp.float32),
+                jnp.asarray(ys, jnp.float32),
+                jnp.asarray(flags), keys, top_t=top_t)
         self._n_host[flagged] += 1
         self._sr_host[flagged] += 1
         self._refit_flagged(flagged)
@@ -745,8 +748,9 @@ class StudyEngine:
         `inv_refresh` appends the factor and its maintained inverse are
         rebuilt from the Gram under the current params — re-anchoring the
         float32 drift the incremental bordered-inverse updates accumulate
-        (DESIGN.md §4).  Both events are rare O(n_max^3) dispatches; the
-        check itself reads only the host-side counter mirrors.
+        (DESIGN.md §4).  Both events are rare O(n_max^3) dispatches, each an
+        `engine.reanchor` span carrying the study's n; the check itself
+        reads only the host-side counter mirrors.
         """
         lag = self.cfg.lag
         inv_refresh = getattr(self.cfg, "inv_refresh", 0)
@@ -755,12 +759,14 @@ class StudyEngine:
         for s in flagged:
             if lag > 0:
                 if self.since_refit(s) >= lag:
-                    self._state = self._refit_at(
-                        self.state, *self._desc_args(),
-                        jnp.asarray(s, jnp.int32))
+                    with span("engine.reanchor", n=self.n(s)):
+                        self._state = self._refit_at(
+                            self.state, *self._desc_args(),
+                            jnp.asarray(s, jnp.int32))
                     self._sr_host[s] = 0
             elif self.since_refit(s) >= inv_refresh:
-                self._state = self._reanchor_at(
-                    self.state, *self._desc_args(),
-                    jnp.asarray(s, jnp.int32))
+                with span("engine.reanchor", n=self.n(s)):
+                    self._state = self._reanchor_at(
+                        self.state, *self._desc_args(),
+                        jnp.asarray(s, jnp.int32))
                 self._sr_host[s] = 0

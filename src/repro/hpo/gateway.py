@@ -40,8 +40,35 @@ which slot it lands in.
 The gateway is asyncio-native and single-threaded: `ask` is a coroutine,
 `tell` a plain enqueue, and one background ticker task drives the rounds.
 Synchronous callers (tests, benchmarks) can instead call `tick()` directly
-for deterministic control.  Telemetry per tick (coalesce width, queue
-depth, latency, evictions) accumulates in `gateway.stats`.
+for deterministic control.
+
+Telemetry.  Each finished tick appends one record to `gateway.stats` (the
+last `stats_window` ticks): `tick` (its id), `width` (asks served),
+`suggestions`, `absorbed`, `deferred`, `queued_after`, `evictions`,
+`restores`, `latency_ms` (stage start to finish, which under pipelining
+also spans the next tick's stage), and the phase times of this tick's own
+two halves, in milliseconds:
+
+  * `queue_wait_ms` — mean over the served asks of the wait from `ask()` /
+    `ask_nowait()` to the stage that took them (a deferred ask counts from
+    its first enqueue; 0.0 when `width` is 0);
+  * `stage_ms`, `finish_ms` — wall time of the stage and the finish;
+  * `wait_ms` — the part of `finish_ms` the host spent blocked on the
+    device, reading the round's suggestions back;
+  * `keys_ms` — the part of `stage_ms` spent splitting the asking
+    studies' PRNG keys, read-back included.
+
+Each phase time is the duration of a host span (`repro.hpo.telemetry`):
+`gateway.tick_stage` and `gateway.tick_finish` (both tagged `tick=<id>`)
+around `gateway.place`, `pool.round_begin`, `pool.split_keys`,
+`engine.advance`, `engine.reanchor` (`n=`, one per re-anchor),
+`pool.materialize`, `pool.mint` and `pool.ask_q`; besides them
+`gateway.evict` and `gateway.restore` (one per study moved) and
+`gateway.yield`, the ticker's cooperative yields, where the clients run.
+Spans are always on and cost about half a microsecond each; their number
+per tick does not grow with its width.  An operator reads the counters in
+`gw.stats`, or the spans on the device trace's clock by serving inside
+`jax.profiler.trace(dir)`: they lie on the trace's `/host:CPU` plane.
 """
 from __future__ import annotations
 
@@ -59,6 +86,7 @@ from repro.core.gp import (BackpressureError, GPCapacityError,
                            StudySaturatedError)
 from repro.hpo.pool import SchedulerConfig, StudyPool, Trial
 from repro.hpo.space import SearchSpace, space_from_dicts, space_to_dicts
+from repro.hpo.telemetry import span
 
 __all__ = ["GatewayConfig", "StudyGateway"]
 
@@ -124,7 +152,9 @@ class _PendingTick:
 
     Holds everything `_tick_finish` needs to commit the round once the
     in-flight device program materializes: the popped queues, the slot
-    placements, and the pool's pending round handle.
+    placements, the pool's pending round handle, and the tick's own phase
+    tally, so its `stats` record holds this tick's stage, not the
+    neighbouring one that overlaps its finish.
     """
 
     round: object                 # pool._PendingRound
@@ -136,6 +166,8 @@ class _PendingTick:
     t0: float
     evictions: int
     restores: int
+    phases: dict                  # span name -> wall ms (telemetry.span)
+    queue_wait_ms: float          # mean enqueue-to-stage wait of `take`
 
     @property
     def size(self) -> int:
@@ -176,7 +208,8 @@ class StudyGateway:
         # the next checkpoint COMMIT (never before — a crash must restore
         # a registry whose studies are all still on disk)
         self._next_sid = 0
-        self._asks: deque[tuple[int, asyncio.Future | None, int]] = deque()
+        # queued asks: (sid, future, q, perf_counter at enqueue)
+        self._asks: deque[tuple] = deque()
         self._tells: list[tuple[int, Trial, float]] = []
         self._tick_count = 0
         self.stats: deque[dict] = deque(maxlen=self.gw.stats_window)
@@ -331,7 +364,7 @@ class StudyGateway:
         loop = asyncio.get_running_loop()
         self._ensure_ticker(loop)
         fut: asyncio.Future = loop.create_future()
-        self._asks.append((sid, fut, q))
+        self._asks.append((sid, fut, q, time.perf_counter()))
         log.pending_asks += q
         self._wake.set()
         return await fut
@@ -341,7 +374,7 @@ class StudyGateway:
         suggestions land in the study's ledger).  For sync callers/tests."""
         log = self._require(sid)
         self._admit_ask(log, q)
-        self._asks.append((sid, None, q))
+        self._asks.append((sid, None, q, time.perf_counter()))
         log.pending_asks += q
         if self._wake is not None:
             self._wake.set()
@@ -481,13 +514,16 @@ class StudyGateway:
 
         The snapshot commits BEFORE any bookkeeping changes: a failed write
         raises with the study still resident and serving (and any prior
-        committed snapshot still the restore target)."""
+        committed snapshot still the restore target).  One
+        `gateway.evict` span."""
         slot = log.slot
-        snap = self.pool.export_study(slot)
-        ckpt_mod.save_study(self.cfg.ckpt_dir, self._study_key(log),
-                            log.version + 1, snap["tree"],
-                            metadata={"handle": json.dumps(snap["meta"]),
-                                      "sid": log.sid, "n_obs": log.n_obs})
+        with span("gateway.evict"):
+            snap = self.pool.export_study(slot)
+            ckpt_mod.save_study(self.cfg.ckpt_dir, self._study_key(log),
+                                log.version + 1, snap["tree"],
+                                metadata={"handle": json.dumps(snap["meta"]),
+                                          "sid": log.sid,
+                                          "n_obs": log.n_obs})
         log.version += 1
         log.slot = None
         log.evicted_ever = True
@@ -501,28 +537,31 @@ class StudyGateway:
     def _ensure_resident(self, sid: int) -> int:
         """Give study `sid` a slot: free-list pop, else LRU eviction; then
         restore-on-demand from its latest partial snapshot (or a blank
-        state if it never held one)."""
+        state if it never held one).  A restore is one `gateway.restore`
+        span."""
         log = self._require(sid)
         if log.slot is not None:
             return log.slot
         slot = self._free.pop() if self._free else self._evict_lru()
         if log.evicted_ever:
-            like = dataclasses.asdict(self.pool.engine.study_state(slot))
-            # version-exact: after a crash/restore, snapshots NEWER than the
-            # registry's version exist (written by the lost timeline) and
-            # must not leak future state into the recovered one
-            out = ckpt_mod.restore_study(self.cfg.ckpt_dir,
-                                         self._study_key(log), like,
-                                         version=log.version)
-            if out is None:
-                raise RuntimeError(
-                    f"study {sid} was evicted but snapshot version "
-                    f"{log.version} is not committed under "
-                    f"{self.cfg.ckpt_dir}")
-            _, tree, meta = out
-            self.pool.import_study(slot, tree,
-                                   json.loads(meta["handle"]),
-                                   space=log.space)
+            with span("gateway.restore"):
+                like = dataclasses.asdict(self.pool.engine.study_state(slot))
+                # version-exact: after a crash/restore, snapshots NEWER than
+                # the registry's version exist (written by the lost
+                # timeline) and must not leak future state into the
+                # recovered one
+                out = ckpt_mod.restore_study(self.cfg.ckpt_dir,
+                                             self._study_key(log), like,
+                                             version=log.version)
+                if out is None:
+                    raise RuntimeError(
+                        f"study {sid} was evicted but snapshot version "
+                        f"{log.version} is not committed under "
+                        f"{self.cfg.ckpt_dir}")
+                _, tree, meta = out
+                self.pool.import_study(slot, tree,
+                                       json.loads(meta["handle"]),
+                                       space=log.space)
             self._restores_this_tick += 1
             self._totals["restores"] += 1
         else:
@@ -620,7 +659,7 @@ class StudyGateway:
         if self._pending is not None:
             pending += self._pending.take
         self._pending = None
-        for _sid, fut, _q in pending:
+        for _sid, fut, *_ in pending:
             if fut is not None and not fut.done():
                 fut.cancel()
 
@@ -784,33 +823,40 @@ class StudyGateway:
 
     def _tick_stage(self) -> _PendingTick | None:
         """Pop the queues, place the involved studies, dispatch the fused
-        round — everything up to (but not including) materialization."""
+        round — everything up to (but not including) materialization.
+
+        Once the queues are popped and any pipeline-hazard flush has
+        landed, the rest (`_stage_round`) is the `gateway.tick_stage`
+        span, tagged with the id the tick's `stats` record will carry; a
+        flushed tick's finish is thus never counted as this tick's stage.
+        """
         tells, self._tells = self._tells, []
         # one ask per study per tick; respect max_batch; keep queue order
-        take: list[tuple[int, asyncio.Future | None, int]] = []
+        take: list[tuple] = []        # queue entries, enqueue time kept
         requeue: deque = deque()
         seen: set[int] = set()
         limit = self.gw.max_batch or len(self._asks)
         while self._asks:
-            sid, fut, q = self._asks.popleft()
+            entry = self._asks.popleft()
+            sid = entry[0]
             if sid in seen or len(take) >= limit:
-                requeue.append((sid, fut, q))
+                requeue.append(entry)
             else:
                 seen.add(sid)
-                take.append((sid, fut, q))
+                take.append(entry)
         self._asks = requeue
         if not tells and not take:
             # nothing new to stage — let the in-flight tick (if any) land
             self.tick_flush()
             return None
         if self._pending is not None and (
-                any(q > 1 for _sid, _fut, q in take)
+                any(q > 1 for _sid, _fut, q, *_ in take)
                 or any(self._studies[sid].slot is None
-                       for sid, _fut, _q in take)
+                       for sid, *_ in take)
                 or any(self._studies[sid].slot is None
                        for sid, _tr, _val in tells)
                 or any(self._needs_escalation(self._studies[sid], q)
-                       for sid, _fut, q in take)):
+                       for sid, _fut, q, *_ in take)):
             # pipeline hazards (§13): residency changes re-rank the LRU and
             # snapshot engine state, q>1 asks append fantasy rows whose
             # rollback bookkeeping the next round's staging reads, and tier
@@ -822,49 +868,66 @@ class StudyGateway:
                 self._tells = tells + self._tells
                 self._asks.extendleft(reversed(take))
                 raise
+        phases: dict = {}
+        tick = self._tick_count + 1 + (self._pending is not None)
+        with span("gateway.tick_stage", phases, tick=tick):
+            return self._stage_round(tells, take, phases)
+
+    def _stage_round(self, tells: list, take: list,
+                     phases: dict) -> _PendingTick | None:
+        """Place the popped tells and asks (`gateway.place`) and dispatch
+        the fused round (`pool.round_begin`); `take` holds queue entries,
+        whose enqueue times give the tick's `queue_wait_ms`."""
         self._restores_this_tick = 0
         self._evictions_this_tick = 0
         t0 = time.perf_counter()
-        # Tells MUST place (their observation has nowhere else to go); their
-        # pending counters pin them against the evictions they trigger.
-        try:
-            events = [(self._ensure_resident(sid), tr, val)
-                      for sid, tr, val in tells]
-        except GPCapacityError as e:
-            # every slot pinned by other in-flight work: nothing was
-            # absorbed (placement precedes the dispatch) — requeue the
-            # tells untouched, fail this tick's asks loudly
-            self._tells = tells + self._tells
-            for sid, fut, q in take:
-                self._studies[sid].pending_asks -= q
-                if fut is not None and not fut.done():
-                    fut.set_exception(e)
-            raise
-        except Exception:
-            # IO fault in the eviction store: nothing was dispatched —
-            # requeue the whole tick untouched and surface the error
-            self._tells = tells + self._tells
-            self._asks.extendleft(reversed(take))
-            raise
-        # Asks place best-effort: the overflow defers to the next tick.
-        ask_slots: dict[int, int] = {}
-        deferred: list[tuple[int, asyncio.Future | None, int]] = []
-        served: list[tuple[int, asyncio.Future | None, int]] = []
-        try:
-            for sid, fut, q in take:
-                slot = self._try_resident(sid)
-                if slot is None:
-                    deferred.append((sid, fut, q))
-                else:
-                    ask_slots[sid] = slot
-                    served.append((sid, fut, q))
-        except Exception:
-            # IO fault placing an ask (eviction snapshot failed): requeue
-            # everything untouched — already-placed asks keep their slots
-            # and replace them idempotently next tick — and surface.
-            self._tells = tells + self._tells
-            self._asks.extendleft(reversed(take))
-            raise
+        with span("gateway.place", phases):
+            # Tells MUST place (their observation has nowhere else to go);
+            # their pending counters pin them against the evictions they
+            # trigger.
+            try:
+                events = [(self._ensure_resident(sid), tr, val)
+                          for sid, tr, val in tells]
+            except GPCapacityError as e:
+                # every slot pinned by other in-flight work: nothing was
+                # absorbed (placement precedes the dispatch) — requeue the
+                # tells untouched, fail this tick's asks loudly
+                self._tells = tells + self._tells
+                for sid, fut, q, *_ in take:
+                    self._studies[sid].pending_asks -= q
+                    if fut is not None and not fut.done():
+                        fut.set_exception(e)
+                raise
+            except Exception:
+                # IO fault in the eviction store: nothing was dispatched —
+                # requeue the whole tick untouched and surface the error
+                self._tells = tells + self._tells
+                self._asks.extendleft(reversed(take))
+                raise
+            # Asks place best-effort: the overflow defers to the next tick.
+            ask_slots: dict[int, int] = {}
+            deferred: list[tuple] = []
+            served: list[tuple[int, asyncio.Future | None, int]] = []
+            waited = 0.0
+            try:
+                for entry in take:
+                    sid, fut, q = entry[:3]
+                    slot = self._try_resident(sid)
+                    if slot is None:
+                        deferred.append(entry)
+                    else:
+                        ask_slots[sid] = slot
+                        served.append((sid, fut, q))
+                        # an entry without a time counts as queued now
+                        waited += t0 - entry[3] if len(entry) > 3 else 0.0
+            except Exception:
+                # IO fault placing an ask (eviction snapshot failed):
+                # requeue everything untouched — already-placed asks keep
+                # their slots and replace them idempotently next tick — and
+                # surface.
+                self._tells = tells + self._tells
+                self._asks.extendleft(reversed(take))
+                raise
         self._asks.extendleft(reversed(deferred))
         take = served
         if not events and not take:
@@ -881,7 +944,7 @@ class StudyGateway:
         one_slots = sorted(ask_slots[sid] for sid, _f, q in take if q == 1)
         try:
             round_ = self.pool.advance_round_begin(
-                events, t=1, studies=one_slots)
+                events, t=1, studies=one_slots, phases=phases)
         except GPCapacityError as e:
             # advance_round capacity-checks the WHOLE round before mutating
             # any ledger or GP buffer (all-or-nothing), so the queues can be
@@ -900,7 +963,10 @@ class StudyGateway:
                             events=events, ask_slots=ask_slots,
                             deferred=len(deferred), t0=t0,
                             evictions=self._evictions_this_tick,
-                            restores=self._restores_this_tick)
+                            restores=self._restores_this_tick,
+                            phases=phases,
+                            queue_wait_ms=1e3 * waited / len(take)
+                            if take else 0.0)
 
     def _fail_tick(self, tells, take, err) -> None:
         """Settle a failed tick so observations don't vanish and clients
@@ -929,7 +995,25 @@ class StudyGateway:
 
     def _tick_finish(self, p: _PendingTick) -> int:
         """Materialize a staged round and commit it: settle ledgers,
-        resolve futures, record telemetry, run the checkpoint cadence."""
+        resolve futures, record telemetry, run the checkpoint cadence.
+
+        The commit is the `gateway.tick_finish` span; the tick's `stats`
+        record is appended once it has closed, with the phase times of
+        this tick's own stage and finish (the cadence's snapshot, when it
+        fires, runs after the span)."""
+        with span("gateway.tick_finish", p.phases,
+                  tick=self._tick_count + 1):
+            record = self._commit_round(p)
+        record["finish_ms"] = p.phases["gateway.tick_finish"]
+        self.stats.append(record)
+        if self.gw.ckpt_every_ticks and \
+                self._tick_count % self.gw.ckpt_every_ticks == 0:
+            self.checkpoint()
+        return p.size
+
+    def _commit_round(self, p: _PendingTick) -> dict:
+        """`_tick_finish`'s commit: returns the tick's `stats` record, all
+        but `finish_ms`."""
         tells, take, ask_slots = p.tells, p.take, p.ask_slots
         try:
             suggestions = p.round.finish()
@@ -945,7 +1029,8 @@ class StudyGateway:
             if q == 1:
                 continue
             try:
-                q_results[sid] = self.pool.ask_q(ask_slots[sid], q)
+                with span("pool.ask_q", p.phases):
+                    q_results[sid] = self.pool.ask_q(ask_slots[sid], q)
             except Exception as e:  # noqa: BLE001 — meted to the future
                 q_results[sid] = e
         latency_ms = 1e3 * (time.perf_counter() - p.t0)
@@ -996,7 +1081,9 @@ class StudyGateway:
             if fut is not None:
                 fut.set_result(trials if q > 1 else trials[0])
         self._sync_fantasy_totals()
-        self.stats.append({
+        self._totals["asks_served"] += n_suggested
+        self._totals["absorbed"] += len(p.events)
+        return {
             "tick": self._tick_count,
             "width": len(take),
             "suggestions": n_suggested,
@@ -1006,13 +1093,11 @@ class StudyGateway:
             "latency_ms": latency_ms,
             "evictions": p.evictions,
             "restores": p.restores,
-        })
-        self._totals["asks_served"] += n_suggested
-        self._totals["absorbed"] += len(p.events)
-        if self.gw.ckpt_every_ticks and \
-                self._tick_count % self.gw.ckpt_every_ticks == 0:
-            self.checkpoint()
-        return p.size
+            "queue_wait_ms": p.queue_wait_ms,
+            "stage_ms": p.phases["gateway.tick_stage"],
+            "wait_ms": p.phases.get("pool.materialize", 0.0),
+            "keys_ms": p.phases.get("pool.split_keys", 0.0),
+        }
 
     def _unwind_capacity_failure(self, tells, take, err) -> bool:
         """Rebuild the queues after an all-or-nothing capacity abort.
@@ -1084,7 +1169,8 @@ class StudyGateway:
                 else:
                     # One cooperative yield: every client task already
                     # runnable gets to enqueue before the round fires.
-                    await asyncio.sleep(0)
+                    with span("gateway.yield"):
+                        await asyncio.sleep(0)
                 progressed = 0
                 self._retry_absorb = False
                 try:
@@ -1097,14 +1183,16 @@ class StudyGateway:
                             # still in flight — without it the staged round
                             # always drains at the tail below and nothing
                             # ever overlaps
-                            await asyncio.sleep(0)
+                            with span("gateway.yield"):
+                                await asyncio.sleep(0)
                         if self._pending is not None and not (
                                 self._asks or self._tells):
                             # pipeline tail: no new traffic arrived — land
                             # the staged round so its clients aren't parked
                             # behind an idle gateway
                             progressed += self.tick_flush()
-                            await asyncio.sleep(0)
+                            with span("gateway.yield"):
+                                await asyncio.sleep(0)
                     else:
                         progressed = self.tick()
                 except GPCapacityError:
@@ -1130,7 +1218,7 @@ class StudyGateway:
                         except Exception:  # noqa: BLE001 — already failing
                             pass
                     while self._asks:
-                        sid, fut, q = self._asks.popleft()
+                        sid, fut, q, *_ = self._asks.popleft()
                         self._studies[sid].pending_asks -= q
                         if fut is not None and not fut.done():
                             fut.set_exception(e)
@@ -1159,7 +1247,7 @@ class StudyGateway:
             except asyncio.CancelledError:
                 pass
         self.tick_flush()  # land any round the ticker left in flight
-        for sid, fut, q in self._asks:
+        for sid, fut, q, *_ in self._asks:
             if fut is not None and not fut.done():
                 fut.cancel()
             self._studies[sid].pending_asks -= q
@@ -1207,8 +1295,11 @@ class StudyGateway:
         """Serving telemetry: counts are LIFETIME totals (including the
         fantasy rollback count and the q-width histogram, which survive
         checkpoint/restore); `fantasy_active` is the LIVE number of
-        fantasy rows across resident slots; latency/width distributions
-        cover the retained window (`stats_window` ticks)."""
+        fantasy rows across resident slots; the width and tick-latency
+        distributions (`latency_ms`) cover the retained window
+        (`stats_window` ticks).  The per-tick phase times (`stage_ms`,
+        `finish_ms`, `wait_ms`, `keys_ms`, `queue_wait_ms`; the module
+        docstring) are read from `stats` itself."""
         self._sync_fantasy_totals()
         out = {"ticks": self._tick_count, **self._totals,
                "fantasy_active": sum(self.pool.fantasy_active(s)
@@ -1313,7 +1404,7 @@ class StudyGateway:
         # clients parked on pre-restore asks belong to the discarded
         # timeline: cancel their futures (dropping them silently would
         # hang those tasks forever — aclose() does the same)
-        for _sid, fut, _q in self._asks:
+        for _sid, fut, *_ in self._asks:
             if fut is not None and not fut.done():
                 fut.cancel()
         self._asks.clear()

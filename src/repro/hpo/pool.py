@@ -47,6 +47,7 @@ from repro.core import neural_basis as nb_mod
 from repro.core.kernels import KernelParams
 from repro.hpo.engine import StudyEngine
 from repro.hpo.space import SearchSpace
+from repro.hpo.telemetry import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,8 +140,10 @@ class _PendingRound:
     (fantasy rollback, overflow drain, fused advance, clamp copy,
     refantasize), so the device program stream — and therefore the final
     state bits — are identical whether or not the host defers `finish()`.
-    `finish()` only does host work: materialize the suggestions, flip the
-    absorbed trials' ledger status, and mint the ledger Trial objects.
+    `finish()` only does host work: materialize the suggestions (the
+    `pool.materialize` span, where the host waits for the device), flip the
+    absorbed trials' ledger status, and mint the ledger Trial objects
+    (`pool.mint`), timing both into the round's `phases` tally.
 
     The pending record holds ONLY fresh dispatch outputs (`units`, a
     copied clamp vector) — never a reference into `engine.state`, whose
@@ -148,10 +151,11 @@ class _PendingRound:
     """
 
     __slots__ = ("_pool", "_first", "_ids", "_need_seed", "_t",
-                 "_units", "_clamps", "_nb_units", "_finished")
+                 "_units", "_clamps", "_nb_units", "_phases", "_finished")
 
     def __init__(self, pool: "StudyPool", first: dict, ids: list,
-                 need_seed: set, t: int, units, clamps, nb_units=None):
+                 need_seed: set, t: int, units, clamps, nb_units=None,
+                 phases: dict | None = None):
         self._pool = pool
         self._first = first
         self._ids = ids
@@ -160,6 +164,7 @@ class _PendingRound:
         self._units = units
         self._clamps = clamps
         self._nb_units = nb_units or {}
+        self._phases = phases
         self._finished = False
 
     def finish(self) -> dict[int, list[Trial]]:
@@ -168,9 +173,12 @@ class _PendingRound:
             raise RuntimeError("pending round already finished")
         self._finished = True
         pool = self._pool
-        units = None if self._units is None else _materialize(self._units)
-        if self._first:
-            clamps = np.asarray(self._clamps)
+        with span("pool.materialize", self._phases):
+            units = None if self._units is None else \
+                _materialize(self._units)
+            clamps = np.asarray(self._clamps) if self._first else None
+        out: dict[int, list[Trial]] = {}
+        with span("pool.mint", self._phases):
             # "done" only after the fused round committed (see absorb())
             for sid, (tr, val) in self._first.items():
                 tr.status = "done"
@@ -178,17 +186,17 @@ class _PendingRound:
                 tr.finished = time.time()
                 tr.clamp_count = int(clamps[sid])
             pool._n_done += len(self._first)
-        out: dict[int, list[Trial]] = {}
-        for s in self._ids:
-            if s in self._need_seed:
-                out[s] = pool.seed_trials(s, self._t)
-            elif s in self._nb_units:
-                # escalated tenants: their suggestions come off the NB
-                # posterior's own staged dispatch, not the GP stack's lane
-                out[s] = [pool._make_trial(s, u)
-                          for u in _materialize(self._nb_units[s])]
-            else:
-                out[s] = [pool._make_trial(s, u) for u in units[s]]
+            for s in self._ids:
+                if s in self._need_seed:
+                    out[s] = pool.seed_trials(s, self._t)
+                elif s in self._nb_units:
+                    # escalated tenants: their suggestions come off the NB
+                    # posterior's own staged dispatch, not the GP stack's
+                    # lane
+                    out[s] = [pool._make_trial(s, u)
+                              for u in _materialize(self._nb_units[s])]
+                else:
+                    out[s] = [pool._make_trial(s, u) for u in units[s]]
         pool._maybe_checkpoint()
         return out
 
@@ -273,20 +281,22 @@ class StudyPool:
         h.key, sub = jax.random.split(h.key)
         return sub
 
-    def _split_many(self, ids: Sequence[int]) -> np.ndarray:
+    def _split_many(self, ids: Sequence[int],
+                    phases: dict | None = None) -> np.ndarray:
         """Advance several studies' PRNG streams in ONE vmapped dispatch.
 
         Returns the subkeys as a host `(len(ids), 2)` uint32 array; values
         are bit-identical to per-study `_split` calls (threefry is
         elementwise), so batched and routed suggest paths draw the same
-        streams.
+        streams.  Timed, read-back included, as `pool.split_keys`.
         """
         if not ids:
             return np.zeros((0, 2), np.uint32)
-        stacked = jnp.stack([self.studies[s].key for s in ids])
-        new = np.asarray(jax.vmap(jax.random.split)(stacked))
-        for j, s in enumerate(ids):
-            self.studies[s].key = jnp.asarray(new[j, 0])
+        with span("pool.split_keys", phases):
+            stacked = jnp.stack([self.studies[s].key for s in ids])
+            new = np.asarray(jax.vmap(jax.random.split)(stacked))
+            for j, s in enumerate(ids):
+                self.studies[s].key = jnp.asarray(new[j, 0])
         return new[:, 1]
 
     def state(self, study_id: int) -> gp_mod.LazyGPState:
@@ -461,11 +471,12 @@ class StudyPool:
             gp_mod.ensure_capacity(self.engine.n(sid), self.cfg.n_max,
                                    incoming=c + len(self._fantasies[sid]))
 
-    def _staged_keys(self, ei_ids: Sequence[int]) -> jax.Array:
+    def _staged_keys(self, ei_ids: Sequence[int],
+                     phases: dict | None = None) -> jax.Array:
         """(S, 2) key batch: fresh subkeys for `ei_ids` (their streams
         advance, one batched split), dummy zeros for everyone else (their
         lane computes alongside but the result is discarded)."""
-        subs = self._split_many(list(ei_ids))
+        subs = self._split_many(list(ei_ids), phases)
         keys_np = np.zeros((self.n_studies, 2), np.uint32)
         keys_np[list(ei_ids)] = subs
         return jnp.asarray(keys_np)
@@ -507,7 +518,8 @@ class StudyPool:
     def advance_round_begin(self,
                             events: Sequence[tuple[int, Trial, float]],
                             t: int = 1,
-                            studies: Sequence[int] | None = None
+                            studies: Sequence[int] | None = None,
+                            phases: dict | None = None
                             ) -> _PendingRound:
         """Stage a fused serving round: dispatch everything, defer commits.
 
@@ -517,7 +529,9 @@ class StudyPool:
         host-side half — materialize suggestions, flip told trials to
         "done", mint ledger Trials.  The pipelined gateway stages tick t+1
         while tick t's program is still in flight on the device; calling
-        `finish()` immediately is exactly `advance_round`.
+        `finish()` immediately is exactly `advance_round`.  The staging is
+        timed as `pool.round_begin` into `phases` (the tick's tally, which
+        the returned round's `finish()` adds its own phases to).
 
         All-or-nothing guards run at STAGE time: a capacity error raises
         here with no ledger or buffer mutated (beyond the fantasy rollback,
@@ -525,76 +539,82 @@ class StudyPool:
         only failure left is a device runtime fault, which surfaces at
         `finish()` before any ledger flip.
         """
-        ids = list(studies) if studies is not None else \
-            list(range(self.n_studies))
-        nb_set = {s for s in range(self.n_studies) if self.engine.tier(s)}
-        if not events:
-            # deferred suggest_all: same stream staging and seed routing,
-            # with the materialization/minting left to finish()
-            need_ei = sorted(s for s in ids
-                             if s not in nb_set and self.engine.n(s) > 0)
-            units = None
-            if need_ei:
-                units = self.engine.suggest_all(self._staged_keys(need_ei),
-                                                top_t=t)[0]
+        with span("pool.round_begin", phases):
+            ids = list(studies) if studies is not None else \
+                list(range(self.n_studies))
+            nb_set = {s for s in range(self.n_studies)
+                      if self.engine.tier(s)}
+            if not events:
+                # deferred suggest_all: same stream staging and seed
+                # routing, with the materialization/minting left to finish()
+                need_ei = sorted(s for s in ids
+                                 if s not in nb_set and self.engine.n(s) > 0)
+                units = None
+                if need_ei:
+                    units = self.engine.suggest_all(
+                        self._staged_keys(need_ei, phases), top_t=t)[0]
+                nb_units = {s: self.engine.nb_suggest(s, self._split(s),
+                                                      top_t=t)[0]
+                            for s in ids if s in nb_set}
+                return _PendingRound(self, {}, ids,
+                                     set(ids) - set(need_ei) - nb_set,
+                                     t, units, None, nb_units, phases)
+            if not ids:
+                self.absorb_many(events)
+                return _PendingRound(self, {}, [], set(), t, None, None,
+                                     phases=phases)
+            # Escalated tenants' completions take the routed NB absorb
+            # (their ledger doubles instead of filling — no fused GP lane to
+            # share); the GP-tier events keep the one-per-study fused-round
+            # split.
+            nb_events = [e for e in events if e[0] in nb_set]
+            gp_events = [e for e in events if e[0] not in nb_set]
+            first: dict[int, tuple[Trial, float]] = {}
+            overflow = []
+            for sid, tr, val in gp_events:
+                if sid in first:
+                    overflow.append((sid, tr, val))
+                else:
+                    first[sid] = (tr, val)
+            # Fantasy rollback BEFORE the capacity check and any absorb:
+            # told studies are truncated to their real ledger (bitwise), so
+            # every append below lands exactly where a never-fantasized run
+            # would put it; survivors are re-fantasized after the round.
+            self._rollback_for_events(events)
+            self._check_capacity(events)
+            if nb_events:
+                self.absorb_many(nb_events, _fantasies_handled=True)
+            if overflow:
+                self.absorb_many(overflow, _fantasies_handled=True)
+            dim = self.engine.gp_cfg.dim
+            flags = np.zeros((self.n_studies,), bool)
+            xs = np.zeros((self.n_studies, dim), np.float32)
+            ys = np.zeros((self.n_studies,), np.float32)
+            costs = np.ones((self.n_studies,), np.float32)
+            for sid, (tr, val) in first.items():
+                flags[sid] = True
+                xs[sid] = tr.unit
+                ys[sid] = float(val)
+                costs[sid] = tr.cost
+            # Studies that will still be empty after this absorb get seed
+            # trials; only requested non-seed studies advance their streams.
+            need_seed = {s for s in ids if s not in nb_set
+                         and self.engine.n(s) == 0 and not flags[s]}
+            ei_ids = [s for s in ids
+                      if s not in need_seed and s not in nb_set]
+            units, _ = self.engine.advance(
+                flags, xs, ys, self._staged_keys(ei_ids, phases), top_t=t,
+                costs=costs)
+            # Clamp telemetry is copied into a FRESH device array before the
+            # refantasize (serial read point) — holding `state.clamp_count`
+            # itself would break when the next staged round donates it.
+            clamps = self.engine.state.clamp_count + 0
             nb_units = {s: self.engine.nb_suggest(s, self._split(s),
                                                   top_t=t)[0]
                         for s in ids if s in nb_set}
-            return _PendingRound(self, {}, ids,
-                                 set(ids) - set(need_ei) - nb_set,
-                                 t, units, None, nb_units)
-        if not ids:
-            self.absorb_many(events)
-            return _PendingRound(self, {}, [], set(), t, None, None)
-        # Escalated tenants' completions take the routed NB absorb (their
-        # ledger doubles instead of filling — no fused GP lane to share);
-        # the GP-tier events keep the one-per-study fused-round split.
-        nb_events = [e for e in events if e[0] in nb_set]
-        gp_events = [e for e in events if e[0] not in nb_set]
-        first: dict[int, tuple[Trial, float]] = {}
-        overflow = []
-        for sid, tr, val in gp_events:
-            if sid in first:
-                overflow.append((sid, tr, val))
-            else:
-                first[sid] = (tr, val)
-        # Fantasy rollback BEFORE the capacity check and any absorb: told
-        # studies are truncated to their real ledger (bitwise), so every
-        # append below lands exactly where a never-fantasized run would
-        # put it; survivors are re-fantasized after the round.
-        self._rollback_for_events(events)
-        self._check_capacity(events)
-        if nb_events:
-            self.absorb_many(nb_events, _fantasies_handled=True)
-        if overflow:
-            self.absorb_many(overflow, _fantasies_handled=True)
-        dim = self.engine.gp_cfg.dim
-        flags = np.zeros((self.n_studies,), bool)
-        xs = np.zeros((self.n_studies, dim), np.float32)
-        ys = np.zeros((self.n_studies,), np.float32)
-        costs = np.ones((self.n_studies,), np.float32)
-        for sid, (tr, val) in first.items():
-            flags[sid] = True
-            xs[sid] = tr.unit
-            ys[sid] = float(val)
-            costs[sid] = tr.cost
-        # Studies that will still be empty after this absorb get seed
-        # trials; only requested non-seed studies advance their streams.
-        need_seed = {s for s in ids if s not in nb_set
-                     and self.engine.n(s) == 0 and not flags[s]}
-        ei_ids = [s for s in ids if s not in need_seed and s not in nb_set]
-        units, _ = self.engine.advance(flags, xs, ys,
-                                       self._staged_keys(ei_ids), top_t=t,
-                                       costs=costs)
-        # Clamp telemetry is copied into a FRESH device array before the
-        # refantasize (serial read point) — holding `state.clamp_count`
-        # itself would break when the next staged round donates it.
-        clamps = self.engine.state.clamp_count + 0
-        nb_units = {s: self.engine.nb_suggest(s, self._split(s), top_t=t)[0]
-                    for s in ids if s in nb_set}
-        self._refantasize_pending(sid for sid, _, _ in events)
-        return _PendingRound(self, first, ids, need_seed, t, units, clamps,
-                             nb_units)
+            self._refantasize_pending(sid for sid, _, _ in events)
+            return _PendingRound(self, first, ids, need_seed, t, units,
+                                 clamps, nb_units, phases)
 
     def advance_round(self, events: Sequence[tuple[int, Trial, float]],
                       t: int = 1,
