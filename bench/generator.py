@@ -4,7 +4,10 @@ A traffic file (`bench/traffic/<name>.json`) sets every parameter:
 
   tenants          logical tenants; each holds one open study at a time
   history_tenants  how many tenants start with a told history (<= slots)
-  history          [lo, hi]: that history's length, uniform per tenant
+  history          [lo, hi]: that history's length; the lengths are spread
+                   evenly over lo..hi across the history tenants, in an
+                   order drawn from the seed, so every seed tells the same
+                   number of observations of each length
   workers          closed-loop clients (each waits for its suggestion,
                    evaluates it for its think time, then tells it)
   think            {"dist": "none"} or {"dist": "lognormal", "median_s",
@@ -17,8 +20,12 @@ A traffic file (`bench/traffic/<name>.json`) sets every parameter:
                    rank among tenants with fewer workers than the cap
   hot_shift        null or {"every_s", "share"}: that share of the hot
                    ranks is permuted at that period
-  study_budget     asks per study; the study then closes once every tell
-                   is absorbed and its tenant opens a new one
+  study_obs        observations a study holds, its starting history
+                   included: once history + asks issued reach it, the study
+                   closes (after every tell is absorbed) and its tenant
+                   opens a new one.  `check_params` holds it to
+                   history[1] < study_obs <= n_max - w (w: the most workers
+                   one tenant can have), so no study can escalate
   warmup_s         traffic served before the window opens
   warmup_ticks     gateway ticks that also have to finish before it opens
                    (a cold compile cache makes the first ticks long)
@@ -43,12 +50,29 @@ from reference.objective import neg_levy_unit
 _TENANT, _WORKER, _SHIFT, _FILL = 1, 2, 3, 4
 
 
+def check_params(params: dict, n_max: int) -> None:
+    """Refuse a traffic whose studies could escalate past `n_max`, at any
+    speed of the served path.  Every ask issued to a study counts toward
+    `study_obs`, so the rows the gateway counts against `n_max` (told
+    observations, asks in flight, the ask being served) stay within
+    `study_obs` plus one ask for each of the tenant's w workers.  Each
+    history tenant has to get at least one ask."""
+    pick = params["pick"]
+    w = 1 if pick["dist"] == "own" else int(pick["max_workers_per_tenant"])
+    top, obs = int(params["history"][1]), int(params["study_obs"])
+    if not top < obs <= n_max - w:
+        raise ValueError(
+            f"study_obs {obs} has to exceed the longest history ({top}) and "
+            f"be at most n_max {n_max} less {w} worker(s) per tenant "
+            f"({n_max - w}): otherwise a study can escalate past n_max")
+
+
 @dataclasses.dataclass
 class Tenant:
     idx: int
     shift: np.ndarray
     sid: int = -1
-    issued: int = 0            # asks issued to the open study
+    held: int = 0              # history + asks issued to the open study
     workers: int = 0
     rotation: asyncio.Future | None = None
 
@@ -85,6 +109,7 @@ class Traffic:
         self.tells = 0
         self.tell_failures = 0
         self.captured: dict[int, dict] = {}
+        self.closes: list[tuple[float, int, int]] = []  # (time, tick, tenant)
         self.stopping = False
         pick = params["pick"]
         if pick["dist"] == "zipf":
@@ -98,7 +123,7 @@ class Traffic:
     # -- studies ------------------------------------------------------------
     def _open(self, t: Tenant) -> None:
         t.sid = self.gw.create_study(name=f"t{t.idx}")
-        t.issued = 0
+        t.held = 0
         self.hist[t.sid] = []
 
     def objective(self, t: Tenant, unit) -> float:
@@ -110,14 +135,17 @@ class Traffic:
         from repro.hpo.pool import Trial
         lo, hi = self.p["history"]
         rng = np.random.default_rng([self.seed, _FILL])
+        n = int(self.p["history_tenants"])
+        mid = 2 * np.arange(n) + 1        # the midpoints of n equal strata
+        lengths = rng.permutation(lo + mid * (hi - lo + 1) // (2 * n))
         told = 0
-        for t in self.tenants[:int(self.p["history_tenants"])]:
-            h = int(rng.integers(lo, hi + 1))
+        for t, h in zip(self.tenants[:n], lengths.tolist()):
             for i, u in enumerate(rng.uniform(0.0, 1.0, (h, self.dim))
                                   .astype(np.float32)):
                 y = self.objective(t, u)
                 self.gw.tell(t.sid, Trial(10 ** 6 + i, u, {}), y)
                 self.hist[t.sid].append((u, y))
+            t.held = h
             told += h
         if told:
             self.gw.tick()
@@ -141,20 +169,21 @@ class Traffic:
         y = self.objective(t, unit)
         self.gw.tell(t.sid, trial, y)
         self.hist[t.sid].append((unit, y))
-        t.issued += 1
+        t.held += 1
         self.gw.tick()
 
     async def _study_for(self, t: Tenant) -> int:
-        """The open study of tenant `t`, rotating it at the study budget:
-        the first worker to find it spent waits until every suggestion of
-        the old study is told and absorbed, closes it, and opens a new
-        one; the tenant's other workers wait for that."""
+        """The open study of tenant `t`, rotating it once it holds
+        `study_obs` observations: the first worker to find it full waits
+        until every suggestion of the old study is told and absorbed,
+        closes it, and opens a new one; the tenant's other workers wait
+        for that."""
         while True:
             if t.rotation is not None:
                 await t.rotation
                 continue
-            if t.issued < int(self.p["study_budget"]):
-                t.issued += 1
+            if t.held < int(self.p["study_obs"]):
+                t.held += 1
                 return t.sid
             t.rotation = asyncio.get_running_loop().create_future()
             old = t.sid
@@ -167,6 +196,8 @@ class Traffic:
             if self.capture is not None and self.p.get("capture_closed"):
                 self.captured[old] = self.capture(self.gw, old)
             self.gw.close_study(old)
+            self.closes.append((time.perf_counter(), self.gw._tick_count,
+                                t.idx))
             self._open(t)
             fut, t.rotation = t.rotation, None
             fut.set_result(None)
@@ -213,7 +244,7 @@ class Traffic:
                     except Exception:  # noqa: BLE001 — counted as failed
                         self.asks.append(Ask(t0, time.perf_counter(), False,
                                              sid, -1, None))
-                        t.issued -= 1
+                        t.held -= 1
                         await asyncio.sleep(0.01)
                         continue
                     t1 = time.perf_counter()

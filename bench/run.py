@@ -81,6 +81,11 @@ def load_cell(name: str, overrides=None):
     cfg = _load_json(ROOT / conf["file"])
     traffic = _load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     _override(cfg, traffic, overrides)
+    from generator import check_params
+    try:
+        check_params(traffic, int(cfg["scheduler"]["n_max"]))
+    except ValueError as e:
+        raise SystemExit(f"traffic {cell['traffic']!r}: {e}") from None
     return spec, cell, cfg, traffic
 
 
@@ -295,6 +300,9 @@ def _run_cell(args, spec, cell, cfg, traffic_p, seed, store, clock, devs,
     _log(f"compiles inside the window: "
          f"{marks['compiles_end'] - marks['compiles']}; set-up compile "
          f"{marks['compile_s']:.3f} s")
+    _log(f"studies closed inside the window: "
+         f"{sum(1 for c in traffic.closes if t_open <= c[0] < t_close)} "
+         f"({len(traffic.closes)} in the run)")
     from repro.kernels import ops
     n_pad = ops._round_up(int(cfg["scheduler"]["n_max"]))
     tile = ops.acq_tile_config(n_pad, gw.pool.engine.gp_cfg.dim,

@@ -22,12 +22,12 @@ TINY_CELL = {
     # one client, study after study, each closed study's state kept
     "sequential": ["traffic.tenants=1", "traffic.history_tenants=0",
                    "traffic.history=[0,0]", "traffic.workers=1",
-                   "traffic.study_budget=25", "traffic.capture_closed=true"],
+                   "traffic.study_obs=25", "traffic.capture_closed=true"],
     # the same configuration under a many-tenant churned traffic mix: more
     # tenants than slots (eviction and restore), a Zipf hot set, think times
     "churn": ["traffic.tenants=16", "traffic.history_tenants=8",
               "traffic.history=[4,12]", "traffic.workers=6",
-              "traffic.study_budget=20",
+              "traffic.study_obs=24",
               'traffic.think={"dist": "lognormal", "median_s": 0.02, '
               '"sigma": 1.0}',
               'traffic.session={"dist": "geometric", "mean": 8}',
@@ -35,10 +35,11 @@ TINY_CELL = {
               '"max_workers_per_tenant": 4}',
               'traffic.hot_shift={"every_s": 0.5, "share": 0.1}',
               "traffic.capture_closed=false"],
-    # every tenant resident and asking on every tick
+    # every tenant resident and asking on every tick, its studies closing
+    # at 28 observations (n_max 32 less one ask in flight, and some room)
     "resident": ["traffic.tenants=8", "traffic.history_tenants=8",
                  "traffic.history=[4,12]", "traffic.workers=8",
-                 "traffic.study_budget=20", "traffic.capture_closed=false"],
+                 "traffic.study_obs=28", "traffic.capture_closed=false"],
 }
 
 

@@ -1,10 +1,12 @@
 """Whole runs of the harness on the CPU at tiny sizes: a sound run is
-correct, each planted fault of the timed path makes `correct` false, and
-the harness refuses to measure without a chip."""
+correct, studies close and reopen without escalating, each planted fault
+of the timed path makes `correct` false, and the harness refuses to
+measure without a chip."""
 import json
 
 import pytest
 
+import generator
 import run
 from conftest import tiny_argv
 
@@ -22,6 +24,31 @@ def test_sound_run_is_correct(cell, capsys):
     assert res["attempted"] > 0 and res["failed"] == 0
     assert "setup_s" in res["metrics"]
     assert list(res)[-1] == "checks"
+
+
+def test_resident_studies_close_and_reopen(capsys, monkeypatch):
+    """Every tenant of the tiny resident run fills a study to its
+    `study_obs` (history included) and opens a new one; the closes fall
+    in different ticks, no study escalates, and the run is correct."""
+    made = []
+
+    class Kept(generator.Traffic):
+        def __init__(self, *a, **k):
+            super().__init__(*a, **k)
+            made.append(self)
+
+    monkeypatch.setattr(generator, "Traffic", Kept)
+    argv = tiny_argv("resident", 4_000_000_013, seconds=3.0)
+    assert run.main(argv, require_chip=False) == 0
+    out = capsys.readouterr().out.strip().splitlines()
+    res = json.loads(out[-1])
+    assert res["correct"], res["checks"]
+    assert res["checks"]["escalated"][0] == 0
+    closes = made[0].closes
+    assert {idx for _, _, idx in closes} == set(range(8))
+    assert len({tick for _, tick, _ in closes}) > 1
+    assert any(line.startswith("studies closed inside the window: ")
+               for line in out)
 
 
 @pytest.mark.parametrize("fault,number", [
