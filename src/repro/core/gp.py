@@ -229,13 +229,29 @@ def _recompute_alpha(state: LazyGPState,
     return jnp.where(_active_mask(state), z @ state.li_buf, 0.0)
 
 
+def _centered_cov(kernel: KernelFn, x_buf: Array, x: Array, params,
+                  implementation: str = "auto") -> Array:
+    """k(x_buf, x) as an (n_max,) column, evaluated on the differences.
+
+    Every kernel here is stationary, so k(x_i, x) = k(x_i - x, 0): the
+    squared distances are then plain sums of squares.  The expanded
+    |x|^2 + |y|^2 - 2 x.y^T cancels for near-duplicate points (EI clusters
+    them) with a float32 error that, at a noise floor of 1e-5, perturbs the
+    factor by more than the noise does (DESIGN.md §4).
+    """
+    origin = jnp.zeros((1, x.shape[-1]), x_buf.dtype)
+    return ops.kernel_gram(kernel, x_buf - x[None, :], origin, params,
+                           implementation=implementation)[:, 0]
+
+
 def _cov_column(state: LazyGPState, kernel: KernelFn, x_new: Array,
                 implementation: str = "auto") -> tuple[Array, Array]:
     """(p_pad, c): covariances of x_new against actives (padded) and itself."""
-    p = ops.kernel_gram(kernel, state.x_buf, x_new[None, :], state.params,
-                        implementation=implementation)[:, 0]
+    p = _centered_cov(kernel, state.x_buf, x_new, state.params,
+                      implementation)
     p_pad = jnp.where(_active_mask(state), p, 0.0)
-    c = kernel(x_new[None, :], x_new[None, :], state.params)[0, 0] + state.params.noise2
+    origin = jnp.zeros((1, x_new.shape[-1]), x_new.dtype)
+    c = kernel(origin, origin, state.params)[0, 0] + state.params.noise2
     return p_pad, c
 
 
@@ -400,13 +416,14 @@ def fantasize(state: LazyGPState, kernel: KernelFn, xs: Array,
     n_new = state.n + q
     # Column i covers actives + earlier fantasy rows: rows idx < n + i of
     # the final point buffer.
-    p_all = ops.kernel_gram(kernel, x_buf, xs, state.params,
-                            implementation=implementation)   # (n_max, q)
+    p_all = jax.vmap(lambda x: _centered_cov(
+        kernel, x_buf, x, state.params, implementation),
+        out_axes=1)(xs)                                       # (n_max, q)
     cols = jnp.where(idx[:, None] < (state.n + jnp.arange(q))[None, :],
                      p_all, 0.0)
-    cs = jax.vmap(lambda x: kernel(x[None, :], x[None, :],
-                                   state.params)[0, 0])(xs) \
-        + state.params.noise2
+    origin = jnp.zeros((1, xs.shape[-1]), xs.dtype)
+    cs = jnp.full((q,), kernel(origin, origin, state.params)[0, 0]
+                  + state.params.noise2)
     mask_new = idx < n_new
     ymean = jnp.sum(jnp.where(mask_new, y_buf, 0.0)) / jnp.maximum(n_new, 1)
     resid = jnp.where(mask_new, y_buf - ymean, 0.0)

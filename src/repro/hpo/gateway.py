@@ -56,7 +56,7 @@ two halves, in milliseconds:
   * `wait_ms` — the part of `finish_ms` the host spent blocked on the
     device, reading the round's suggestions back;
   * `keys_ms` — the part of `stage_ms` spent splitting the asking
-    studies' PRNG keys, read-back included.
+    studies' PRNG keys (one split of the pool's host key table).
 
 Each phase time is the duration of a host span (`repro.hpo.telemetry`):
 `gateway.tick_stage` and `gateway.tick_finish` (both tagged `tick=<id>`)

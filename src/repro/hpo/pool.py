@@ -203,16 +203,21 @@ class _PendingRound:
 
 @dataclasses.dataclass
 class StudyHandle:
-    """Host-side per-tenant record: ledger, id counter, PRNG streams."""
+    """Host-side per-tenant record: ledger, id counter, seed-trial stream
+    (the study's PRNG key is its row of the pool's key table)."""
 
     study_id: int
     space: SearchSpace
     name: str
     trials: list[Trial] = dataclasses.field(default_factory=list)
     next_id: int = 0
-    key: jax.Array | None = None
     rng: np.random.Generator | None = None  # seed-trial stream; persistent
     # so repeated seeding draws fresh points, never the same batch twice
+
+
+# One program splits the whole (S, 2) key table; run on the host's CPU
+# device, it is bit-identical to a per-study `jax.random.split`.
+_split_table = jax.jit(jax.vmap(jax.random.split))
 
 
 class StudyPool:
@@ -248,9 +253,14 @@ class StudyPool:
                                   descs=descs)
         self.studies = [
             StudyHandle(i, sp, names[i],
-                        key=jax.random.PRNGKey(cfg.seed + i),
                         rng=np.random.default_rng(cfg.seed + i))
             for i, sp in enumerate(spaces)]
+        # Per-study PRNG streams: one host uint32 row per slot, advanced by
+        # `_advance_keys` (the split program is compiled here, once per S).
+        self._cpu = jax.devices("cpu")[0]
+        self._keys = np.stack([self._seed_key(cfg.seed + i)
+                               for i in range(len(spaces))])
+        _split_table(jax.device_put(self._keys, self._cpu))
         self._done_at_last_ckpt = 0
         self._n_done = 0  # absorptions ever (ckpt cadence + monotonic step;
         # counts absorbs into since-evicted slots, unlike total_done())
@@ -276,28 +286,37 @@ class StudyPool:
         h.trials.append(tr)
         return tr
 
-    def _split(self, study_id: int) -> jax.Array:
-        h = self.studies[study_id]
-        h.key, sub = jax.random.split(h.key)
-        return sub
+    def _seed_key(self, seed: int) -> np.ndarray:
+        """`jax.random.PRNGKey(seed)` as a host `(2,)` uint32 row."""
+        with jax.default_device(self._cpu):
+            return np.asarray(jax.random.PRNGKey(seed))
+
+    def _advance_keys(self, ids: Sequence[int]) -> np.ndarray:
+        """Advance the PRNG streams of the studies in `ids`.
+
+        Splits the whole key table in one fixed-shape call on the host's
+        CPU device, whatever `ids` holds (so a new count of asking studies
+        compiles nothing), keeps the new key only in the rows of `ids`, and
+        returns their subkeys as a host `(len(ids), 2)` uint32 array:
+        bit-identical to a `jax.random.split` of each study's key.
+        """
+        new = np.asarray(_split_table(jax.device_put(self._keys, self._cpu)))
+        mask = np.zeros((self.n_studies, 1), bool)
+        mask[ids] = True
+        self._keys = np.where(mask, new[:, 0], self._keys)
+        return new[ids, 1]
+
+    def _split(self, study_id: int) -> np.ndarray:
+        return self._advance_keys([study_id])[0]
 
     def _split_many(self, ids: Sequence[int],
                     phases: dict | None = None) -> np.ndarray:
-        """Advance several studies' PRNG streams in ONE vmapped dispatch.
-
-        Returns the subkeys as a host `(len(ids), 2)` uint32 array; values
-        are bit-identical to per-study `_split` calls (threefry is
-        elementwise), so batched and routed suggest paths draw the same
-        streams.  Timed, read-back included, as `pool.split_keys`.
-        """
+        """Advance several studies' PRNG streams at once (`_advance_keys`),
+        timed as `pool.split_keys`."""
         if not ids:
             return np.zeros((0, 2), np.uint32)
         with span("pool.split_keys", phases):
-            stacked = jnp.stack([self.studies[s].key for s in ids])
-            new = np.asarray(jax.vmap(jax.random.split)(stacked))
-            for j, s in enumerate(ids):
-                self.studies[s].key = jnp.asarray(new[j, 0])
-        return new[:, 1]
+            return self._advance_keys(ids)
 
     def state(self, study_id: int) -> gp_mod.LazyGPState:
         """Unstacked single-study GP view."""
@@ -803,7 +822,7 @@ class StudyPool:
                             dataclasses.asdict(self.engine.study_state(slot)))
         meta = {"name": h.name, "next_id": h.next_id,
                 "trials": self.history(slot),
-                "key": np.asarray(h.key).tolist(),
+                "key": self._keys[slot].tolist(),
                 "rng_state": h.rng.bit_generator.state,
                 # escalation tier (DESIGN.md §15): the tag, the per-row
                 # tell costs (float32 -> float64 -> JSON is exact), and —
@@ -837,7 +856,7 @@ class StudyPool:
                 self.engine.set_desc(slot, space.descriptor())
         h.name = meta["name"]
         h.next_id = int(meta["next_id"])
-        h.key = jnp.asarray(np.asarray(meta["key"], np.uint32))
+        self._keys[slot] = np.asarray(meta["key"], np.uint32)
         h.rng = np.random.default_rng()
         h.rng.bit_generator.state = meta["rng_state"]
         h.trials = [_trial_from_dict(t) for t in meta["trials"]]
@@ -866,7 +885,7 @@ class StudyPool:
         h.name = name if name is not None else f"study{slot}"
         h.trials = []
         h.next_id = 0
-        h.key = jax.random.PRNGKey(seed)
+        self._keys[slot] = self._seed_key(seed)
         h.rng = np.random.default_rng(seed)
 
     # -- checkpointing (the whole pool rides one atomic snapshot) -----------
@@ -906,7 +925,7 @@ class StudyPool:
                  "next_id": h.next_id, "trials": self.history(h.study_id),
                  # per-study PRNG streams ride the snapshot so a restored
                  # pool never re-draws batches it already drew pre-crash
-                 "key": np.asarray(h.key).tolist(),
+                 "key": self._keys[h.study_id].tolist(),
                  "rng_state": h.rng.bit_generator.state}
                 for h in self.studies]),
             # Escalated-tier state (DESIGN.md §15) rides the snapshot as
@@ -960,7 +979,7 @@ class StudyPool:
             h.name = rec["name"]
             h.next_id = int(rec["next_id"])
             if "key" in rec:
-                h.key = jnp.asarray(np.asarray(rec["key"], np.uint32))
+                self._keys[h.study_id] = np.asarray(rec["key"], np.uint32)
             if "rng_state" in rec:
                 h.rng = np.random.default_rng()
                 h.rng.bit_generator.state = rec["rng_state"]

@@ -32,7 +32,7 @@ a leading study axis per DESIGN.md §7):
   chol_append        | active factor     |   P    |  x  |  x  | no*      | §6
   gp_posterior_solve | active factor     |   P    |  x  |  x  | no*      | §6
   kernel_gram        | any kernel fn     |   P†   |  x  |  x  | yes      | §6
-  masked_gram        | padded buffers    |   P†   |  x  |  x  | yes      | §3
+  masked_gram        | padded buffers    |   -    |  x  |  x  | yes      | §3,§4
   padded_trsv        | padded buffers    |   P    |  x  |  x  | yes      | §3
   padded_cholesky    | padded buffers    |   P    |  x  |  x  | yes      | §3
   padded_tri_inverse | padded buffers    |   P    |  x  |  x  | yes      | §4
@@ -345,17 +345,25 @@ def masked_gram(x_buf: Array, n: Array, kernel_fn, params,
     result is the identity-padded factor (the lag-event refactorization
     input).  `n` may be traced; the output shape is always (n_max, n_max).
 
+    Row i is the kernel of the differences `x_buf - x_i` against the origin
+    (every kernel here is stationary), so each squared distance is a plain
+    sum of squares, as in the appends' covariance columns (`gp._cov_column`):
+    the expanded |x|^2 + |y|^2 - 2 x.y^T cancels for near-duplicate points
+    with a float32 error above a 1e-5 noise floor.  An O(n_max^2 d)
+    elementwise build, so it runs as plain jnp for every implementation.
+
     Batched form: `x_buf (S, n_max, d)` with per-study `n (S,)` and `params`
     whose leaves carry a leading `(S,)` axis builds S padded Grams in one
     dispatch.
     """
+    del implementation  # elementwise: no substrate dispatch below this line
     if x_buf.ndim == 3:
         return jax.vmap(lambda xb, nn, pp: masked_gram(
-            xb, nn, kernel_fn, pp,
-            implementation=implementation))(x_buf, n, params)
-    n_max = x_buf.shape[0]
-    k = kernel_gram(kernel_fn, x_buf, x_buf, params,
-                    implementation=implementation)
+            xb, nn, kernel_fn, pp))(x_buf, n, params)
+    n_max, d = x_buf.shape
+    origin = jnp.zeros((1, d), x_buf.dtype)
+    k = jax.vmap(lambda xi: kernel_fn(x_buf - xi[None, :], origin,
+                                      params)[:, 0])(x_buf)
     eye = jnp.eye(n_max, dtype=k.dtype)
     k = k + params.noise2 * eye
     idx = jnp.arange(n_max)

@@ -1,6 +1,8 @@
 """Multi-tenant StudyPool tests: batched suggest, routed/queued absorption,
 per-study isolation (capacity, faults, lag, telemetry), pool checkpointing,
 and the one-code-path contract with TrialScheduler."""
+import dataclasses
+import json
 import tempfile
 
 import numpy as np
@@ -310,3 +312,98 @@ def test_scheduler_is_one_study_pool():
     sched.absorb(tr, 1.0)
     assert sched.pool.engine.n(0) == 1
     assert int(sched.state.n) == 1
+
+
+# -- the pool's key table: per-study PRNG streams ----------------------------
+
+def _ref_split(ref: list, s: int) -> np.ndarray:
+    """Advance the reference chain of study s by one plain split."""
+    import jax
+    ref[s], sub = jax.random.split(ref[s])
+    return np.asarray(sub)
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 + 5])
+def test_key_table_streams_match_per_study_split_chains(seed):
+    """Every subkey the table hands out equals the one a study's own
+    `jax.random.split` chain draws, under a random mix of single and
+    batched splits over id subsets of changing width (empty and all S
+    included), a `reset_study(seed=...)` and an export/import round trip."""
+    import jax
+    S = 5
+    pool = StudyPool([RESNET_SPACE] * S, SchedulerConfig(n_max=8, seed=seed))
+    ref = [jax.random.PRNGKey(seed + i) for i in range(S)]
+    rng = np.random.default_rng(seed % 1000)
+    widths = [0, S, 1, 3, S, 0, 2, 4, 1, S]
+    for step, w in enumerate(widths):
+        ids = sorted(rng.choice(S, size=w, replace=False).tolist())
+        got = pool._split_many(ids)
+        assert got.shape == (w, 2) and got.dtype == np.uint32
+        for j, s in enumerate(ids):
+            np.testing.assert_array_equal(got[j], _ref_split(ref, s))
+        s = int(rng.integers(S))
+        np.testing.assert_array_equal(pool._split(s), _ref_split(ref, s))
+        if step == 3:
+            pool.reset_study(2, seed=seed + 77)
+            ref[2] = jax.random.PRNGKey(seed + 77)
+        if step == 6:
+            snap = pool.export_study(1)
+            assert snap["meta"]["key"] == np.asarray(ref[1]).tolist()
+            pool.import_study(4, snap["tree"], snap["meta"])
+            ref[4] = ref[1]
+    for s in range(S):
+        np.testing.assert_array_equal(pool._split(s), _ref_split(ref, s))
+
+
+def test_key_table_compiles_nothing_for_new_widths():
+    """After the first round, serving a changing number of asking studies
+    compiles no program: the split runs one fixed shape for every width."""
+    import jax
+    compiles = []
+
+    def on_event(event, secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    cfg = SchedulerConfig(n_max=16, seed=0)
+    pool = StudyPool([RESNET_SPACE] * 4, cfg)
+    rng = np.random.default_rng(0)
+    for s in range(4):       # every study past its seed trial
+        u = rng.uniform(size=3).astype(np.float32)
+        pool.absorb(s, pool._make_trial(s, u), float(s))
+    pool.advance_round([], studies=[0, 1, 2, 3])      # the first round
+    pool.suggest(0)                           # and the one-study program
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for ids in ([0], [1, 3], [0, 1, 2], [2], [0, 1, 2, 3], []):
+            pool.advance_round([], studies=ids)
+            pool._split_many(ids)
+        pool.suggest(2)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+
+
+def test_checkpoint_key_format_restores_the_same_next_subkey():
+    """The snapshot keeps each study's key as a two-item list, as it always
+    has: a checkpoint written with a key in that form restores to the same
+    next subkey as a plain split of that key."""
+    import jax
+    from repro import checkpoint as ckpt_mod
+    with tempfile.TemporaryDirectory() as d:
+        cfg = SchedulerConfig(n_max=8, seed=3, ckpt_dir=d)
+        pool = StudyPool([RESNET_SPACE] * 3, cfg)
+        pool._split_many([0, 2])
+        pool.checkpoint()
+        _, _, meta = ckpt_mod.restore_latest(
+            d, dataclasses.asdict(pool.engine.state))
+        keys = [rec["key"] for rec in json.loads(meta["studies"])]
+        assert all(isinstance(k, list) and len(k) == 2 and
+                   all(isinstance(v, int) for v in k) for k in keys)
+        pool2 = StudyPool([RESNET_SPACE] * 3, cfg)
+        assert pool2.restore()
+        for s, k in enumerate(keys):
+            want = np.asarray(jax.random.split(
+                jax.numpy.asarray(k, jax.numpy.uint32))[1])
+            np.testing.assert_array_equal(pool2._split(s), want)
+            np.testing.assert_array_equal(pool._split(s), want)

@@ -151,3 +151,25 @@ def test_served_advance_compiles(one_chip, served_engines, monkeypatch,
         state, *desc, arg((s, d)), arg((s,)), arg((s,), bool),
         arg((s, 2), jnp.uint32), top_t=1).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("space", ["float", "mixed"])
+def test_served_reanchor_and_fantasy_compile(one_chip, served_engines,
+                                             monkeypatch, space):
+    """The re-anchor (the Gram built on differences, then the Pallas
+    Cholesky and inverse) and the q-ask's fantasy rows (covariance columns
+    on differences through the Pallas gram, under two vmaps), as `auto`
+    builds them on a TPU."""
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    eng = served_engines[space]
+    s, d = eng.n_studies, eng.gp_cfg.dim
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    state = jax.tree.map(spec, eng.state)
+    desc = (jax.tree.map(spec, eng.desc),) if eng.mixed else ()
+    i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    hlo = eng._reanchor_at.lower(state, *desc, i).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    xs = jax.ShapeDtypeStruct((4, d), jnp.float32, sharding=one_chip)
+    hlo = eng._refantasize_at.lower(state, *desc, i, xs).compile().as_text()
+    assert "tpu_custom_call" in hlo
